@@ -95,7 +95,7 @@ def batched_plan_2d(verts: jax.Array, valid: jax.Array,
 def batched_plan_runs_2d(verts: jax.Array, valid: jax.Array,
                          axis0: jax.Array, axis1: jax.Array,
                          max_rows: int, use_pallas: bool = False,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Run-pair form of :func:`batched_plan_2d`: the compressed plan
     representation, straight from the fused pipeline.
 

@@ -13,7 +13,7 @@ planner's (``Slicer(fast_paths=False)`` per-index reference, and the
 default fast-path planner wherever the two agree) — every comparison
 and interpolation in the pipeline mirrors the host formulas
 operation-for-operation, and the pipeline runs in float64 by default
-(``jax.experimental.enable_x64``; pass ``dtype=np.float32`` for the
+(``jax.enable_x64``; pass ``dtype=np.float32`` for the
 TPU-native approximate mode).  ``SliceStats`` accounting (§5.2) is
 reproduced exactly: dim-2 slices = candidate rows, dim-1 slices =
 leading span indices + emitted leaf points pre-dedupe.
@@ -67,7 +67,7 @@ class DevicePlanner:
     """Fused-pipeline planner with transparent host fallback."""
 
     def __init__(self, datacube: Datacube, use_pallas: bool = False,
-                 interpret: bool = True, dtype=np.float64,
+                 interpret: bool | None = None, dtype=np.float64,
                  max_jobs: int = MAX_JOBS):
         self.datacube = datacube
         self.use_pallas = use_pallas
@@ -297,7 +297,7 @@ class DevicePlanner:
 
     def _invoke(self, verts, valid, bases, scalars, g, max_rows):
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         from repro.kernels._casting import checked_cast_i32
         from repro.kernels.plan import ops as plan_ops

@@ -70,25 +70,26 @@ def gather(flat_data: Any, plan: ExtractionPlan,
            use_kernel: bool = False, burst: bool = False) -> Any:
     """Read exactly the planned elements.
 
-    ``burst=True`` issues one wide copy per coalesced run
-    (run-length-aware DMA) instead of one load per element; results are
-    identical — runs tile the offsets exactly.
+    A numpy payload is indexed on the host; a device payload is read on
+    its device.  ``burst=True`` reads the aligned windows that hold the
+    plan's coalesced runs (run-length-aware DMA) instead of one load per
+    element, and ``use_kernel`` runs that read as the Pallas kernel;
+    results are identical — runs tile the offsets exactly.
     """
     if isinstance(flat_data, np.ndarray):
         return flat_data[plan.offsets]
-    import jax.numpy as jnp
-
-    if burst:
+    if burst or use_kernel:
         from repro.kernels.gather import ops as gops
 
         return gops.gather_plan_runs(flat_data, plan.run_starts,
                                      plan.run_lengths,
                                      use_pallas=use_kernel)
-    offs = jnp.asarray(plan.offsets)
-    if use_kernel:
-        from repro.kernels.gather import ops as gops
+    import jax.numpy as jnp
 
-        return gops.gather_rows(flat_data[:, None], offs)[:, 0]
+    from repro.kernels import checked_cast_i32
+
+    offs = checked_cast_i32(plan.offsets, what="plan offsets",
+                            n_elements=flat_data.shape[0])
     return jnp.take(flat_data, offs, axis=0)
 
 

@@ -86,8 +86,10 @@ class WeatherCube:
         lon = np.concatenate([
             360.0 * np.arange(c) / c for c in self.cube.row_counts])
         lat_r, lon_r = np.radians(lat_rows), np.radians(lon)
+        # the per-field arithmetic runs in the payload's dtype, so a
+        # float32 archive never holds a float64 field
         base = (15.0 * np.cos(lat_r) + 5.0 * np.sin(2 * lon_r) *
-                np.cos(lat_r))
+                np.cos(lat_r)).astype(self.dtype)
         out = np.empty((self.n_times, self.n_levels,
                         self.cube.points_per_field), self.dtype)
         for t in range(self.n_times):

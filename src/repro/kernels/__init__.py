@@ -17,8 +17,25 @@
 # _casting.checked_cast_i32 is the ONLY place an offset-carrying array
 # may be cast to the kernels' int32 index dtype (enforced by the
 # unchecked-i32-cast lint rule in repro.analysis).
-from . import gather, paged_attn, plan, segment, slice  # noqa: F401
-from ._casting import checked_cast_i32, ensure_i32_addressable
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Pallas interpret mode: ``interpret`` if given, else true exactly
+    when JAX's default backend is the CPU.  Every kernel wrapper defaults
+    to ``None`` and resolves it here, so a chip run compiles the kernel
+    and a CPU run interprets it without either caller saying so."""
+    if interpret is not None:
+        return interpret
+    import jax
+
+    return jax.default_backend() == "cpu"
+
+
+# The subpackages import resolve_interpret from here, so it is defined
+# before they load.
+from . import gather, paged_attn, plan, segment, slice  # noqa: E402,F401
+from ._casting import checked_cast_i32, ensure_i32_addressable  # noqa: E402
 
 __all__ = ["gather", "paged_attn", "plan", "segment", "slice",
-           "checked_cast_i32", "ensure_i32_addressable"]
+           "checked_cast_i32", "ensure_i32_addressable",
+           "resolve_interpret"]

@@ -1,10 +1,10 @@
 """Public jit'd entry points for extraction gathers.
 
-``use_pallas`` selects the Pallas kernel (interpret=True on CPU — the
-kernel body runs in Python for validation; on TPU pass
-``interpret=False``).  The default dispatch keeps the pure-jnp path for
-host-only runs so the whole framework works identically with or without
-the kernels — kernels are an optimisation layer, not a dependency.
+``use_pallas`` selects the Pallas kernel, which runs in interpret mode
+on the CPU backend and compiles on a TPU (``repro.kernels
+.resolve_interpret``).  The default dispatch keeps the pure-jnp path so
+the whole framework works identically with or without the kernels —
+kernels are an optimisation layer, not a dependency.
 """
 
 from __future__ import annotations
@@ -13,19 +13,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels._casting import checked_cast_i32
+from repro.kernels._casting import checked_cast_i32, ensure_i32_addressable
 
 from . import kernel, ref
 
-# Burst chunk width in elements: one DMA per chunk; runs longer than
-# this split into several wide copies, shorter ones over-read into the
-# padded tail and compact afterwards.
+# Burst window width in elements: the payload is read as aligned
+# windows of this many elements, one DMA per distinct window a plan
+# touches; the planned elements are compacted out afterwards.
 BURST_BLOCK = 128
 
 
 def gather_rows(table: jax.Array, indices: jax.Array,
                 use_pallas: bool = False,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     if use_pallas:
         return kernel.gather_rows(table, indices, interpret=interpret)
     return ref.gather_rows(table, indices)
@@ -33,7 +33,7 @@ def gather_rows(table: jax.Array, indices: jax.Array,
 
 def gather_rows_bag(table: jax.Array, bags: jax.Array,
                     use_pallas: bool = False,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     if use_pallas:
         return kernel.gather_rows_bag(table, bags, interpret=interpret)
     return ref.gather_rows_bag(table, bags)
@@ -42,67 +42,50 @@ def gather_rows_bag(table: jax.Array, bags: jax.Array,
 def chunk_runs(run_starts: np.ndarray, run_lengths: np.ndarray,
                block: int = BURST_BLOCK
                ) -> tuple[np.ndarray, np.ndarray]:
-    """Split coalesced plan runs into ≤``block``-element DMA chunks.
+    """Map coalesced plan runs onto the aligned ``block``-element windows
+    of the payload that hold them.
 
     Pure numpy (host side — plan post-processing, not kernel work).
-    Returns (chunk_starts (C,) int64, gather_idx (N,) int64): chunk c
-    covers elements [chunk_starts[c], chunk_starts[c] + block) of the
-    padded payload, and ``gather_idx`` compacts the (C·block,) chunk
-    lattice back to the plan's N points in offset order.
+    Returns (rows (C,) int64, gather_idx (N,) int64): ``rows`` are the
+    distinct windows the runs touch, ascending, and ``gather_idx``
+    compacts the (C·block,) window lattice back to the plan's N points
+    in run order.
     """
     starts = np.asarray(run_starts, np.int64)
     lens = np.asarray(run_lengths, np.int64)
     if starts.size == 0:
         return np.empty(0, np.int64), np.empty(0, np.int64)
-    n_chunks = -(-lens // block)
-    tot = int(n_chunks.sum())
-    ends = np.cumsum(n_chunks)
-    ordinal = np.arange(tot) - np.repeat(ends - n_chunks, n_chunks)
-    chunk_starts = np.repeat(starts, n_chunks) + ordinal * block
-    chunk_lens = np.minimum(block, np.repeat(lens, n_chunks)
-                            - ordinal * block)
-    cends = np.cumsum(chunk_lens)
-    n = int(cends[-1])
-    ramp = np.arange(n) - np.repeat(cends - chunk_lens, chunk_lens)
-    gather_idx = np.repeat(np.arange(tot) * block, chunk_lens) + ramp
-    return chunk_starts, gather_idx
+    ends = np.cumsum(lens)
+    offsets = (np.repeat(starts - (ends - lens), lens)
+               + np.arange(int(ends[-1]), dtype=np.int64))
+    rows, slot = np.unique(offsets // block, return_inverse=True)
+    return rows, slot.reshape(-1) * block + offsets % block
 
 
 def gather_plan_runs(flat: jax.Array, run_starts: np.ndarray,
                      run_lengths: np.ndarray, block: int = BURST_BLOCK,
                      use_pallas: bool = False,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """Run-length-aware burst gather of an extraction plan.
 
     Reads every planned element of the flat (n,) payload as wide
-    contiguous copies — one DMA per ≤``block``-element chunk of each
-    coalesced run — then compacts the chunk lattice back to the plan's
-    point order.  Byte-equal to ``flat[plan.offsets]``.
+    contiguous copies — one DMA per aligned ``block``-element window the
+    plan's runs touch — then compacts the window lattice back to the
+    plan's point order.  Byte-equal to ``flat[plan.offsets]``.  The
+    payload is read in place: no padded copy is made per call.
     """
-    chunk_starts, gather_idx = chunk_runs(run_starts, run_lengths, block)
-    if chunk_starts.size == 0:
+    rows, gather_idx = chunk_runs(run_starts, run_lengths, block)
+    if rows.size == 0:
         return jnp.zeros((0,), flat.dtype)
-    n_flat = flat.shape[0]
-    cs = checked_cast_i32(chunk_starts, what="burst gather chunk starts",
-                          n_elements=n_flat)
-    # pad so the final chunk's wide window stays in bounds
-    flat_pad = jnp.concatenate([flat, jnp.zeros((block,), flat.dtype)])
+    n_rows = -(-flat.shape[0] // block)
+    ensure_i32_addressable(n_rows * block, what="burst gather windows")
+    rows32 = checked_cast_i32(rows, what="burst gather rows",
+                              n_elements=n_rows)
     if use_pallas:
-        out = kernel.gather_runs(flat_pad, cs, block, interpret=interpret)
+        out = kernel.gather_runs(flat, rows32, block, interpret=interpret)
     else:
-        out = ref.gather_runs(flat_pad, cs, block)
+        out = ref.gather_runs(flat, rows32, block)
     idx = checked_cast_i32(gather_idx,
                            what="burst gather compaction indices",
                            n_elements=out.size)
     return jnp.take(out.reshape(-1), idx)
-
-
-def gather_plan_rows(flat: jax.Array, offsets: jax.Array, row: int,
-                     use_pallas: bool = False) -> jax.Array:
-    """Extraction-plan adapter: gather `row`-sized blocks from a flat
-    datacube payload.  ``offsets`` are block-aligned element offsets from
-    :class:`repro.core.ExtractionPlan` (``run_starts`` coalesced to
-    ``row``-element blocks)."""
-    n = flat.shape[0] // row
-    table = flat[: n * row].reshape(n, row)
-    return gather_rows(table, offsets // row, use_pallas=use_pallas)

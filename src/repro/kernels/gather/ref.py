@@ -24,10 +24,13 @@ def gather_rows_bag(table: jax.Array, bags: jax.Array) -> jax.Array:
     return jnp.sum(jnp.where(valid, rows, 0), axis=1).astype(table.dtype)
 
 
-def gather_runs(flat: jax.Array, chunk_starts: jax.Array,
+def gather_runs(flat: jax.Array, chunk_rows: jax.Array,
                 block: int) -> jax.Array:
-    """Oracle for the burst kernel: strided window loads, (C, block)."""
-    starts = checked_cast_i32(chunk_starts, what="gather_runs chunk starts",
-                              n_elements=flat.shape[0])
-    window = starts[:, None] + jnp.arange(block, dtype=jnp.int32)[None, :]
-    return jnp.take(flat, window, axis=0)
+    """Oracle for the burst kernel: aligned window loads, (C, block).
+    A payload whose length is not a multiple of ``block`` reads zeros
+    past its end, which no plan element maps to."""
+    rows = checked_cast_i32(chunk_rows, what="gather_runs rows",
+                            n_elements=-(-flat.shape[0] // block))
+    window = (rows[:, None] * block
+              + jnp.arange(block, dtype=jnp.int32)[None, :])
+    return jnp.take(flat, window, axis=0, mode="fill", fill_value=0)

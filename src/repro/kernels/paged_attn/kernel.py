@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels._casting import checked_cast_i32
 
 NEG_INF = -1e30
@@ -67,7 +68,7 @@ def _paged_attn_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, out_ref,
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Validate the plan indices host-side, then run the jitted kernel.
 
     ``block_table`` entries are page ids in [0, n_pages) with ``-1``
@@ -87,12 +88,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
                               what="paged_decode_attention seq_lens",
                               n_elements=pmax * ps + 1)
     return _paged_decode_attention(q, k_pages, v_pages, table32, lens32,
-                                   interpret=interpret)
+                                   interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
-                            interpret: bool = True):
+                            interpret: bool):
     b, h, dh = q.shape
     n_pages, kvh, ps, _ = k_pages.shape
     pmax = block_table.shape[1]

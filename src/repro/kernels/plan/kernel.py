@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 from .ref import row_slots_2d
 
 
@@ -69,14 +71,22 @@ def _plan_kernel(verts_ref, valid_ref, base_ref, sv0_ref, rowoff0_ref,
     meta_ref[...] = meta + jnp.stack([n_runs, n_rows, n_points])
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "n0", "n1", "max_rows", "cyclic", "interpret"))
 def plan_runs_2d(verts, valid, base, sv0, rowoff0, sv1, scalars, *,
                  n0: int, n1: int, max_rows: int, cyclic: bool,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """Device pipeline with the ``ref.plan_runs_2d`` contract:
     returns (run_starts (M,) i32, run_lengths (M,) i32, meta (3,) i32)
     with M = J · max_rows · 2, byte-identical to the oracle."""
+    return _plan_runs_2d(verts, valid, base, sv0, rowoff0, sv1, scalars,
+                         n0=n0, n1=n1, max_rows=max_rows, cyclic=cyclic,
+                         interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n0", "n1", "max_rows", "cyclic", "interpret"))
+def _plan_runs_2d(verts, valid, base, sv0, rowoff0, sv1, scalars, *,
+                  n0: int, n1: int, max_rows: int, cyclic: bool,
+                  interpret: bool):
     j, v, _ = verts.shape
     m = j * max_rows * 2
     if j == 0:

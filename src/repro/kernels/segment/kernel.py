@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
 from repro.kernels._casting import checked_cast_i32
 
 BLOCK_E = 256
@@ -44,7 +45,8 @@ def _segment_sum_kernel(seg_ref, msg_ref, out_ref, *, num_segments: int,
 
 
 def segment_sum(messages: jax.Array, segment_ids: jax.Array,
-                num_segments: int, interpret: bool = True) -> jax.Array:
+                num_segments: int,
+                interpret: bool | None = None) -> jax.Array:
     """Validate segment ids host-side (each in [0, num_segments), ``-1``
     padding allowed), cast through the bounds-checked helper, then run
     the jitted one-hot MXU kernel; tracers pass through."""
@@ -52,12 +54,12 @@ def segment_sum(messages: jax.Array, segment_ids: jax.Array,
                              n_elements=num_segments,
                              allow_negative_one=True)
     return _segment_sum(messages, seg32, num_segments,
-                        interpret=interpret)
+                        interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
 def _segment_sum(messages: jax.Array, segment_ids: jax.Array,
-                 num_segments: int, interpret: bool = True) -> jax.Array:
+                 num_segments: int, interpret: bool) -> jax.Array:
     e, d = messages.shape
     pad = (-e) % BLOCK_E
     if pad:

@@ -12,7 +12,7 @@ VMEM_SEGMENT_LIMIT = 512 * 1024  # floats of (S, D) accumulator
 
 def segment_sum(messages: jax.Array, segment_ids: jax.Array,
                 num_segments: int, use_pallas: bool = False,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     d = messages.shape[-1]
     if use_pallas and num_segments * d <= VMEM_SEGMENT_LIMIT:
         return kernel.segment_sum(messages, segment_ids, num_segments,

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 from .ref import PLANE_TOL
 
 BLOCK_P = 8
@@ -58,9 +60,16 @@ def _slice_kernel(verts_ref, valid_ref, planes_ref, out_ref, mask_ref, *,
     mask_ref[...] = out_valid
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def slice_batch(verts: jax.Array, valid: jax.Array, planes: jax.Array,
-                k: int, interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                k: int, interpret: bool | None = None
+                ) -> tuple[jax.Array, jax.Array]:
+    return _slice_batch(verts, valid, planes, k=k,
+                        interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _slice_batch(verts: jax.Array, valid: jax.Array, planes: jax.Array,
+                 k: int, interpret: bool) -> tuple[jax.Array, jax.Array]:
     p, v, d = verts.shape
     pad = (-p) % BLOCK_P
     if pad:

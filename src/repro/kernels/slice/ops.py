@@ -9,7 +9,7 @@ from . import kernel, ref
 
 
 def slice_batch(verts, valid, planes, k: int, use_pallas: bool = False,
-                interpret: bool = True):
+                interpret: bool | None = None):
     if use_pallas:
         return kernel.slice_batch(verts, valid, planes, k,
                                   interpret=interpret)

@@ -6,16 +6,23 @@ Two modes:
                   python -m repro.launch.serve --mode lm --arch glm4-9b
 * ``extract`` — polytope extraction service under a Zipfian request mix
   (the production pattern: a few hot crops dominate traffic), serving
-  plans from the LRU plan cache (DESIGN.md §4):
+  plans from the LRU plan cache (DESIGN.md §4) and reading the float32
+  payload on the device:
                   python -m repro.launch.serve --mode extract --requests 512
+
+Both modes keep JAX's compile cache in ``$JAX_COMPILATION_CACHE_DIR``,
+or in ``<repo>/.jax_cache`` when that is unset.
 """
 
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import numpy as np
+
+from repro.launch import use_compile_cache
 
 
 def run_lm(args) -> None:
@@ -50,43 +57,71 @@ def run_lm(args) -> None:
     print(f"KV pool utilization at end: {engine.pager.utilization:.0%}")
 
 
-def run_extract(args) -> None:
+class ClientError(RuntimeError):
+    """A load-generating client thread raised; the run is not valid."""
+
+
+def load_payload(wc, seed: int):
+    """The cube's float32 payload made from ``seed``: returns the host
+    copy and the same array placed once on JAX's default device."""
+    import jax
+
+    host = wc.field_data(seed)
+    return host, jax.device_put(host)
+
+
+def run_extract(args, payload=None):
     """Closed-loop Zipfian load against the sharded service: ``--threads``
     clients submit through one :class:`AdmissionQueue` (so duplicate hot
     crops coalesce across callers inside each arrival window), and the
-    per-request latency distribution lands in ``BENCH_serve.json``."""
+    per-request latency distribution lands in ``--bench-out``.
+
+    The payload is read on the device: ``payload`` is the device array
+    of ``load_payload`` for the same cube, or ``None`` to build it here.
+    Returns ``(row, answers)``: the bench row and every answered
+    ``ServiceResult`` in submission order per client.  Raises
+    :class:`ClientError` if any client thread raised.
+    """
     import json
     import threading
 
     from repro.dataplane.weather import WeatherCube, request_population
     from repro.serve.sharded import AdmissionQueue, ShardedExtractionService
 
-    wc = WeatherCube(n=args.grid_n, n_times=4, n_levels=4)
-    data = wc.field_data()
+    if args.zipf_s <= 1.0:
+        raise SystemExit("--zipf-s must be > 1 (Zipf exponent)")
+    wc = WeatherCube(n=args.grid_n, n_times=args.n_times,
+                     n_levels=args.n_levels, dtype=np.dtype(np.float32))
+    if payload is None:
+        _, payload = load_payload(wc, args.seed)
     svc = ShardedExtractionService(
         wc.cube, shards=args.shards,
         capacity_per_shard=args.cache_capacity)
     population = request_population(wc)
 
-    if args.zipf_s <= 1.0:
-        raise SystemExit("--zipf-s must be > 1 (Zipf exponent)")
     rng = np.random.default_rng(args.seed)
     ranks = np.minimum(rng.zipf(args.zipf_s, size=args.requests) - 1,
                        len(population) - 1)
     per_thread = np.array_split(ranks, max(args.threads, 1))
     latencies = [np.empty(0)] * len(per_thread)
+    answers: list[list] = [[] for _ in per_thread]
+    errors: list[BaseException | None] = [None] * len(per_thread)
     barrier = threading.Barrier(len(per_thread) + 1)
 
     def client(tid: int, my_ranks: np.ndarray, queue: AdmissionQueue):
         lat = np.empty(len(my_ranks))
         barrier.wait()
-        for i, r in enumerate(my_ranks):
-            t0 = time.perf_counter()
-            queue.extract(population[int(r)], timeout=60)
-            lat[i] = time.perf_counter() - t0
+        try:
+            for i, r in enumerate(my_ranks):
+                t0 = time.perf_counter()
+                answers[tid].append(
+                    queue.extract(population[int(r)], timeout=60))
+                lat[i] = time.perf_counter() - t0
+        except Exception as e:   # reported after the join, never lost
+            errors[tid] = e
         latencies[tid] = lat
 
-    with AdmissionQueue(svc, flat_data=data,
+    with AdmissionQueue(svc, flat_data=payload,
                         window_s=args.window_ms / 1e3) as queue:
         threads = [threading.Thread(target=client, args=(i, tr, queue))
                    for i, tr in enumerate(per_thread)]
@@ -98,6 +133,11 @@ def run_extract(args) -> None:
             t.join()
         dt = time.perf_counter() - t0
         adm = queue.snapshot()
+
+    failed = [e for e in errors if e is not None]
+    if failed:
+        raise ClientError(f"{len(failed)} of {len(per_thread)} client "
+                          f"threads failed: {failed[0]!r}") from failed[0]
 
     lat_ms = np.concatenate(latencies) * 1e3
     if not len(lat_ms):  # --requests 0: an empty but schema-valid row
@@ -130,9 +170,10 @@ def run_extract(args) -> None:
     print(f"planning {s.plan_time_s:.2f}s, shared gather "
           f"{s.gather_time_s:.2f}s, read sharing {s.sharing_factor:.2f}x")
     print(f"wrote {args.bench_out}")
+    return row, [a for per in answers for a in per]
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["lm", "extract"], default="lm")
     ap.add_argument("--requests", type=int, default=8)
@@ -141,6 +182,8 @@ def main() -> None:
     ap.add_argument("--max-new-tokens", type=int, default=16)
     # extract mode
     ap.add_argument("--grid-n", type=int, default=32)
+    ap.add_argument("--n-times", type=int, default=4)
+    ap.add_argument("--n-levels", type=int, default=4)
     ap.add_argument("--threads", type=int, default=8)
     ap.add_argument("--shards", type=int, default=4)
     ap.add_argument("--window-ms", type=float, default=2.0)
@@ -148,10 +191,18 @@ def main() -> None:
     ap.add_argument("--zipf-s", type=float, default=1.3)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--bench-out", default="BENCH_serve.json")
-    args = ap.parse_args()
+    return ap
 
+
+def main(argv: list[str] | None = None) -> None:
+    args = build_parser().parse_args(argv)
+    use_compile_cache()
     if args.mode == "extract":
-        run_extract(args)
+        try:
+            run_extract(args)
+        except ClientError as e:
+            print(f"serve: {e}", file=sys.stderr)
+            raise SystemExit(1) from e
     else:
         from repro.configs import ARCH_IDS
 

@@ -493,7 +493,8 @@ def shared_union_gather(datacube: Datacube,
         from repro.analysis.plan_check import verify_plan
 
         verify_plan(union_plan, datacube=datacube)
-    buf = gather(flat_data, union_plan, use_kernel=use_kernel)
+    # One device→host copy of the union; every answer is sliced from it.
+    buf = np.asarray(gather(flat_data, union_plan, use_kernel=use_kernel))
     per_key: dict[str, Any] = {}
     for key, plan in nonempty.items():
         idx = np.searchsorted(union, plan.offsets)

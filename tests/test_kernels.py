@@ -37,6 +37,27 @@ class TestGatherRows:
         out = gk.gather_rows(table, idx)
         np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(out[1]))
 
+    def test_split_across_calls(self, monkeypatch):
+        monkeypatch.setattr(gk, "MAX_ROWS_PER_CALL", 16)
+        table = jnp.arange(64.0 * 8).reshape(64, 8)
+        idx = (np.arange(45) * 7) % 64
+        np.testing.assert_array_equal(np.asarray(gk.gather_rows(table, idx)),
+                                      np.asarray(table)[idx])
+
+
+class TestBurstGather:
+    def test_unaligned_payload(self):
+        from repro.kernels.gather import ops as go
+
+        flat = jnp.arange(300.0)
+        starts, lens = np.array([5, 250, 290]), np.array([10, 30, 10])
+        want = np.concatenate([np.arange(s, s + n)
+                               for s, n in zip(starts, lens)])
+        np.testing.assert_array_equal(
+            np.asarray(go.gather_plan_runs(flat, starts, lens)), want)
+        with pytest.raises(ValueError, match="divisible"):
+            go.gather_plan_runs(flat, starts, lens, use_pallas=True)
+
 
 class TestGatherBag:
     @pytest.mark.parametrize("n,d,b,l", [(32, 8, 4, 3), (64, 32, 16, 8),
