@@ -169,6 +169,14 @@ def run_extract(args, payload=None):
           f"factor {adm.coalescing_factor:.2f}x")
     print(f"planning {s.plan_time_s:.2f}s, shared gather "
           f"{s.gather_time_s:.2f}s, read sharing {s.sharing_factor:.2f}x")
+    per_win = 1e3 / max(adm.windows, 1)
+    print(f"stages: admission wait "
+          f"{1e3 * adm.wait_s / max(adm.submitted, 1):.2f}ms/req; per "
+          f"window lookup {s.lookup_time_s * per_win:.2f}ms, union "
+          f"{s.union_time_s * per_win:.2f}ms, launch "
+          f"{s.launch_time_s * per_win:.2f}ms, copy "
+          f"{s.copy_time_s * per_win:.2f}ms, slice "
+          f"{s.slice_time_s * per_win:.2f}ms")
     print(f"wrote {args.bench_out}")
     return row, [a for per in answers for a in per]
 

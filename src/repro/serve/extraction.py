@@ -30,6 +30,7 @@ from dataclasses import dataclass, fields, replace
 from typing import Any, Iterable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import PolytopeExtractor, Request, gather
 from repro.core.datacube import Datacube
@@ -57,6 +58,12 @@ class CacheStats:
     delta_hits: int = 0             # misses served by plan splicing
     delta_misses: int = 0           # misses with no splicable neighbor
     delta_time_s: float = 0.0       # cumulative splice walltime
+    lookup_time_s: float = 0.0      # batch loop: hash, route, get, plan
+    # gather_time_s split into its four stages (they sum to it)
+    union_time_s: float = 0.0       # host union build
+    launch_time_s: float = 0.0      # gather call: trace, compile, dispatch
+    copy_time_s: float = 0.0        # device wait + copy back to the host
+    slice_time_s: float = 0.0       # per-request slices of the union
 
     @property
     def lookups(self) -> int:
@@ -83,14 +90,54 @@ class CacheStats:
         return float("inf") if self.bytes_requested else 1.0
 
 
-def merge_stats(parts: Iterable[CacheStats]) -> CacheStats:
+def merge_stats(parts: Iterable[CacheStats],
+                into: CacheStats | None = None) -> CacheStats:
     """Field-wise sum of :class:`CacheStats` (derived rates recompute
-    from the summed counters) — shard aggregation for the sharded cache."""
-    out = CacheStats()
+    from the summed counters) — shard aggregation for the sharded cache.
+    ``into`` accumulates in place (a service folding a batch's delta
+    into its own stats under its own lock); a fresh object otherwise."""
+    out = CacheStats() if into is None else into
     for s in parts:
         for f in fields(CacheStats):
             setattr(out, f.name, getattr(out, f.name) + getattr(s, f.name))
     return out
+
+
+class Stage:
+    """One stage of the served path, recorded twice at once: a
+    ``jax.profiler.TraceAnnotation`` span named ``name`` (``meta`` rides
+    along as its arguments; free while no profiler runs) and, when
+    ``stats`` is given, the stage's ``perf_counter`` length added to
+    ``stats.<counter>``.  ``start`` chains stages: given the previous
+    stage's ``end``, adjacent stages share their boundary, so their
+    lengths sum to the whole."""
+
+    __slots__ = ("stats", "counter", "start", "end", "_span")
+
+    def __init__(self, name: str, stats: Any = None, counter: str = "",
+                 start: float | None = None, **meta: Any):
+        self._span = TraceAnnotation(name, **meta)
+        self.stats = stats
+        self.counter = counter
+        self.start = start
+        self.end = 0.0
+
+    def __enter__(self) -> "Stage":
+        self._span.__enter__()
+        if self.start is None:
+            self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.end = time.perf_counter()
+        self._span.__exit__(*exc)
+        if self.stats is not None:
+            setattr(self.stats, self.counter,
+                    getattr(self.stats, self.counter) + self.seconds)
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
 
 
 class PlanCache:
@@ -355,10 +402,9 @@ class ExtractionService:
             out = self._try_delta(request, key)
             if out is not None:
                 return out
-        t0 = time.perf_counter()
-        plan, stats = self.extractor.plan(request)
-        dt = time.perf_counter() - t0
-        self.cache.stats.plan_time_s += dt  # unlocked-ok: caller holds _lock
+        with Stage("polytope.planner.cold") as st:
+            plan, stats = self.extractor.plan(request)
+        self.cache.stats.plan_time_s += st.seconds  # unlocked-ok: caller holds _lock
         self.cache.put(key, plan)           # unlocked-ok: caller holds _lock
         if self.neighborhood is not None and stats is not None:
             sig, anchor = request.shape_signature(self.tol)
@@ -372,32 +418,32 @@ class ExtractionService:
         plans verify (when ``self.verify``), install under the exact
         key, and re-index — so a drift *chain* keeps splicing from its
         latest member instead of walking back to the origin."""
-        t0 = time.perf_counter()
-        sig, anchor = request.shape_signature(self.tol)
-        for entry in self.neighborhood.candidates(sig):
-            shifts = self.delta_planner.axis_shifts(entry.anchor, anchor)
-            if shifts is None:
-                continue
-            parent = self.cache.peek(entry.key)  # unlocked-ok: caller holds _lock
-            if parent is None:
-                continue   # parent evicted under the index entry
-            out = self.delta_planner.splice(request, entry.request,
-                                            parent, entry.stats, shifts)
-            if out is None:
-                continue
-            plan, stats = out
-            if self.verify:
-                from repro.analysis.plan_check import verify_plan
+        with Stage("polytope.planner.delta") as st:
+            sig, anchor = request.shape_signature(self.tol)
+            for entry in self.neighborhood.candidates(sig):
+                shifts = self.delta_planner.axis_shifts(entry.anchor, anchor)
+                if shifts is None:
+                    continue
+                parent = self.cache.peek(entry.key)  # unlocked-ok: caller holds _lock
+                if parent is None:
+                    continue   # parent evicted under the index entry
+                out = self.delta_planner.splice(request, entry.request,
+                                                parent, entry.stats, shifts)
+                if out is None:
+                    continue
+                plan, stats = out
+                if self.verify:
+                    from repro.analysis.plan_check import verify_plan
 
-                verify_plan(plan, datacube=self.datacube, stats=stats)
-            self.cache.put(key, plan)  # unlocked-ok: caller holds _lock
-            self.neighborhood.add(sig, key, anchor, request, stats)
-            dt = time.perf_counter() - t0
-            self.cache.stats.delta_hits += 1  # unlocked-ok: caller holds _lock
-            self.cache.stats.delta_time_s += dt  # unlocked-ok: caller holds _lock
-            return plan, stats
-        self.cache.stats.delta_misses += 1  # unlocked-ok: caller holds _lock
-        return None
+                    verify_plan(plan, datacube=self.datacube, stats=stats)
+                self.cache.put(key, plan)  # unlocked-ok: caller holds _lock
+                self.neighborhood.add(sig, key, anchor, request, stats)
+                dt = time.perf_counter() - st.start
+                self.cache.stats.delta_hits += 1  # unlocked-ok: caller holds _lock
+                self.cache.stats.delta_time_s += dt  # unlocked-ok: caller holds _lock
+                return plan, stats
+            self.cache.stats.delta_misses += 1  # unlocked-ok: caller holds _lock
+            return None
 
     def extract(self, request: Request,
                 flat_data: Any | None = None) -> ServiceResult:
@@ -413,52 +459,47 @@ class ExtractionService:
         ``flat_data`` is given — all distinct plans are gathered through
         a single coalesced union read shared across the batch.
         """
-        keys = [r.canonical_hash(self.tol, self.periods) for r in requests]
         results: list[ServiceResult] = []
         batch_plans: dict[str, ExtractionPlan] = {}
+        delta = CacheStats()
 
-        with self._lock:
-            for req, key in zip(requests, keys):
-                if key in batch_plans:
-                    # same geometry earlier in this batch — share it
-                    self.cache.stats.batch_dedup += 1
+        with Stage("polytope.plan_cache.lookup", delta, "lookup_time_s"):
+            keys = [r.canonical_hash(self.tol, self.periods)
+                    for r in requests]
+            with self._lock:
+                for req, key in zip(requests, keys):
+                    if key in batch_plans:
+                        # same geometry earlier in this batch — share it
+                        self.cache.stats.batch_dedup += 1
+                        results.append(ServiceResult(
+                            request=req, key=key, plan=batch_plans[key],
+                            cached=True))
+                        continue
+                    plan = self.cache.get(key)
+                    stats = None
+                    cached = plan is not None
+                    if plan is None:
+                        plan, stats = self._plan_miss(req, key)
+                    batch_plans[key] = plan
                     results.append(ServiceResult(
-                        request=req, key=key, plan=batch_plans[key],
-                        cached=True))
-                    continue
-                plan = self.cache.get(key)
-                stats = None
-                cached = plan is not None
-                if plan is None:
-                    plan, stats = self._plan_miss(req, key)
-                batch_plans[key] = plan
-                results.append(ServiceResult(
-                    request=req, key=key, plan=plan, cached=cached,
-                    stats=stats))
+                        request=req, key=key, plan=plan, cached=cached,
+                        stats=stats))
 
         # Gather outside the lock: plans are immutable and the results
         # are local, so concurrent callers only contend on the (short)
         # planning section, not on the batch I/O.  This discipline is no
         # longer just prose: repro.analysis.concurrency statically
         # verifies that all _lock-protected state (the cache) is only
-        # touched inside `with self._lock` blocks — _gather_batch's
-        # stats updates re-enter the lock below.
+        # touched inside `with self._lock` blocks — the batch's timings
+        # and byte counts fold into the stats under the lock below.
+        parts = [delta]
         if flat_data is not None:
-            self._gather_batch(results, batch_plans, flat_data)
-        return results
-
-    def _gather_batch(self, results: list[ServiceResult],
-                      batch_plans: dict[str, ExtractionPlan],
-                      flat_data: Any) -> None:
-        """One union read for the whole batch, then slice each request's
-        values out of the shared buffer (coalesced-run sharing)."""
-        requested, read, dt = shared_union_gather(
-            self.datacube, results, batch_plans, flat_data,
-            use_kernel=self.extractor.use_kernel, verify=self.verify)
+            parts.append(shared_union_gather(
+                self.datacube, results, batch_plans, flat_data,
+                use_kernel=self.extractor.use_kernel, verify=self.verify))
         with self._lock:
-            self.cache.stats.bytes_requested += requested
-            self.cache.stats.bytes_read += read
-            self.cache.stats.gather_time_s += dt
+            merge_stats(parts, into=self.cache.stats)
+        return results
 
 
 def shared_union_gather(datacube: Datacube,
@@ -466,44 +507,55 @@ def shared_union_gather(datacube: Datacube,
                         batch_plans: dict[str, ExtractionPlan],
                         flat_data: Any,
                         use_kernel: bool = False,
-                        verify: bool = False) -> tuple[int, int, float]:
+                        verify: bool = False) -> CacheStats:
     """Execute one coalesced union read for ``batch_plans`` and slice each
     result's values out of the shared buffer.
 
-    Fills ``res.values`` in place and returns
-    ``(bytes_requested, bytes_read, gather_time_s)`` so the caller can
-    fold the accounting into its own stats under its own lock.  Shared
-    between :class:`ExtractionService` and the sharded service in
-    :mod:`repro.serve.sharded` — both funnel a window's distinct plans
-    through exactly one gather.
+    Fills ``res.values`` in place and returns the batch's accounting as
+    a :class:`CacheStats` delta: bytes requested and read,
+    ``gather_time_s`` and its four stages (union build, gather launch,
+    copy back, slicing), which share boundary timestamps and so sum to
+    it.  The caller folds the delta into its own stats under its own
+    lock.  Shared between :class:`ExtractionService` and the sharded
+    service in :mod:`repro.serve.sharded` — both funnel a window's
+    distinct plans through exactly one gather.
     """
+    delta = CacheStats()
     nonempty = {k: p for k, p in batch_plans.items() if p.n_points}
     if not nonempty:
         for res in results:
             res.values = np.empty(0, datacube.dtype)
-        return 0, 0, 0.0
-    t0 = time.perf_counter()
-    union = np.unique(np.concatenate(
-        [p.offsets for p in nonempty.values()]))
-    starts, lengths = coalesce_runs(union)
-    union_plan = ExtractionPlan(
-        offsets=union, run_starts=starts, run_lengths=lengths,
-        coords={}, itemsize=datacube.dtype.itemsize)
-    if verify:
-        from repro.analysis.plan_check import verify_plan
+        return delta
+    with Stage("polytope.gather.union", delta, "union_time_s") as union_st:
+        union = np.unique(np.concatenate(
+            [p.offsets for p in nonempty.values()]))
+        starts, lengths = coalesce_runs(union)
+        union_plan = ExtractionPlan(
+            offsets=union, run_starts=starts, run_lengths=lengths,
+            coords={}, itemsize=datacube.dtype.itemsize)
+        if verify:
+            from repro.analysis.plan_check import verify_plan
 
-        verify_plan(union_plan, datacube=datacube)
+            verify_plan(union_plan, datacube=datacube)
+    with Stage("polytope.gather.launch", delta, "launch_time_s",
+               start=union_st.end) as st:
+        out = gather(flat_data, union_plan, use_kernel=use_kernel)
     # One device→host copy of the union; every answer is sliced from it.
-    buf = np.asarray(gather(flat_data, union_plan, use_kernel=use_kernel))
-    per_key: dict[str, Any] = {}
-    for key, plan in nonempty.items():
-        idx = np.searchsorted(union, plan.offsets)
-        per_key[key] = buf[idx]
-    requested = 0
-    for res in results:
-        if res.plan.n_points:
-            res.values = per_key[res.key]
-        else:
-            res.values = np.empty(0, datacube.dtype)
-        requested += res.plan.nbytes
-    return requested, union_plan.nbytes, time.perf_counter() - t0
+    with Stage("polytope.gather.copy", delta, "copy_time_s",
+               start=st.end) as st:
+        buf = np.asarray(out)
+    with Stage("polytope.gather.slice", delta, "slice_time_s",
+               start=st.end) as st:
+        per_key: dict[str, Any] = {}
+        for key, plan in nonempty.items():
+            idx = np.searchsorted(union, plan.offsets)
+            per_key[key] = buf[idx]
+        for res in results:
+            if res.plan.n_points:
+                res.values = per_key[res.key]
+            else:
+                res.values = np.empty(0, datacube.dtype)
+            delta.bytes_requested += res.plan.nbytes
+    delta.bytes_read = union_plan.nbytes
+    delta.gather_time_s = st.end - union_st.start
+    return delta

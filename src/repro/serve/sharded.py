@@ -46,8 +46,8 @@ from repro.core.index_tree import ExtractionPlan
 from repro.core.shapes import CANON_TOL
 from repro.distributed.sharding import HashRing
 from repro.serve.extraction import (CacheStats, NeighborhoodIndex,
-                                    PlanCache, ServiceResult, merge_stats,
-                                    shared_union_gather)
+                                    PlanCache, ServiceResult, Stage,
+                                    merge_stats, shared_union_gather)
 
 
 # ---------------------------------------------------------------------------
@@ -308,9 +308,9 @@ class ShardedExtractionService:
             if spliced is not None:
                 plan, sstats = spliced
             else:
-                t0 = time.perf_counter()
-                plan, sstats = self.extractor.plan(request)
-                cache.record(plan_time_s=time.perf_counter() - t0)
+                with Stage("polytope.planner.cold") as st:
+                    plan, sstats = self.extractor.plan(request)
+                cache.record(plan_time_s=st.seconds)
                 cache.put(key, plan)
                 self._index_neighbor(request, key, sstats)
         self._ship(key, plan)
@@ -321,32 +321,32 @@ class ShardedExtractionService:
         plan lock).  The signature routes to one shard's neighborhood;
         parent plans fetch cross-shard by their exact keys.  Returns
         ``(plan, stats)`` or ``None`` (→ plan cold)."""
-        t0 = time.perf_counter()
-        sig, anchor = request.shape_signature(self.tol)
-        hood = self.shards.hood_of(sig)
-        for entry in hood.candidates(sig):
-            shifts = self.delta_planner.axis_shifts(entry.anchor, anchor)
-            if shifts is None:
-                continue
-            parent = self.shards.peek(entry.key)
-            if parent is None:
-                continue   # parent evicted under the index entry
-            out = self.delta_planner.splice(request, entry.request,
-                                            parent, entry.stats, shifts)
-            if out is None:
-                continue
-            plan, stats = out
-            if self.verify:
-                from repro.analysis.plan_check import verify_plan
+        with Stage("polytope.planner.delta") as st:
+            sig, anchor = request.shape_signature(self.tol)
+            hood = self.shards.hood_of(sig)
+            for entry in hood.candidates(sig):
+                shifts = self.delta_planner.axis_shifts(entry.anchor, anchor)
+                if shifts is None:
+                    continue
+                parent = self.shards.peek(entry.key)
+                if parent is None:
+                    continue   # parent evicted under the index entry
+                out = self.delta_planner.splice(request, entry.request,
+                                                parent, entry.stats, shifts)
+                if out is None:
+                    continue
+                plan, stats = out
+                if self.verify:
+                    from repro.analysis.plan_check import verify_plan
 
-                verify_plan(plan, datacube=self.datacube, stats=stats)
-            cache.put(key, plan)
-            hood.add(sig, key, anchor, request, stats)
-            cache.record(delta_hits=1,
-                         delta_time_s=time.perf_counter() - t0)
-            return plan, stats
-        cache.record(delta_misses=1)
-        return None
+                    verify_plan(plan, datacube=self.datacube, stats=stats)
+                cache.put(key, plan)
+                hood.add(sig, key, anchor, request, stats)
+                cache.record(delta_hits=1,
+                             delta_time_s=time.perf_counter() - st.start)
+                return plan, stats
+            cache.record(delta_misses=1)
+            return None
 
     def _index_neighbor(self, request: Request, key: str,
                         stats) -> None:
@@ -367,27 +367,28 @@ class ShardedExtractionService:
         read — but with no global lock on the planning path."""
         results: list[ServiceResult] = []
         batch_plans: dict[str, ExtractionPlan] = {}
-        for req in requests:
-            key = req.canonical_hash(self.tol, self.periods)
-            if key in batch_plans:
-                self.shards.entry_of(key)[1].record(batch_dedup=1)
+        delta = CacheStats()
+        with Stage("polytope.plan_cache.lookup", delta, "lookup_time_s"):
+            for req in requests:
+                key = req.canonical_hash(self.tol, self.periods)
+                if key in batch_plans:
+                    self.shards.entry_of(key)[1].record(batch_dedup=1)
+                    results.append(ServiceResult(
+                        request=req, key=key, plan=batch_plans[key],
+                        cached=True))
+                    continue
+                plan, cached, key, sstats = self._plan_one(req, key)
+                batch_plans[key] = plan
                 results.append(ServiceResult(
-                    request=req, key=key, plan=batch_plans[key],
-                    cached=True))
-                continue
-            plan, cached, key, sstats = self._plan_one(req, key)
-            batch_plans[key] = plan
-            results.append(ServiceResult(
-                request=req, key=key, plan=plan, cached=cached,
-                stats=sstats))
+                    request=req, key=key, plan=plan, cached=cached,
+                    stats=sstats))
+        parts = [delta]
         if flat_data is not None:
-            requested, read, dt = shared_union_gather(
+            parts.append(shared_union_gather(
                 self.datacube, results, batch_plans, flat_data,
-                use_kernel=self.extractor.use_kernel, verify=self.verify)
-            with self._io_lock:
-                self.io_stats.bytes_requested += requested
-                self.io_stats.bytes_read += read
-                self.io_stats.gather_time_s += dt
+                use_kernel=self.extractor.use_kernel, verify=self.verify))
+        with self._io_lock:
+            merge_stats(parts, into=self.io_stats)
         return results
 
     # -- topology ----------------------------------------------------------
@@ -438,6 +439,7 @@ class AdmissionStats:
     windows: int = 0        # batches drained
     coalesced: int = 0      # duplicate geometries folded within windows
     window_max: int = 0     # largest window drained
+    wait_s: float = 0.0     # summed submit → drain wait of drained requests
 
     @property
     def coalescing_factor(self) -> float:
@@ -470,6 +472,7 @@ class AdmissionQueue:
         self.max_batch = max_batch
         self.stats = AdmissionStats()
         self._pending: list[tuple[Request, Future]] = []
+        self._stamps: list[float] = []   # perf_counter at each submit
         self._closed = False
         self._lock = threading.Condition()
         self._worker = threading.Thread(target=self._run,
@@ -486,6 +489,7 @@ class AdmissionQueue:
             if self._closed:
                 raise RuntimeError("AdmissionQueue is closed")
             self._pending.append((request, fut))
+            self._stamps.append(time.perf_counter())
             self._lock.notify_all()
         return fut
 
@@ -508,20 +512,25 @@ class AdmissionQueue:
                     return
                 # Window open: wait out the arrival window (or fill up),
                 # then drain everything that accumulated.
-                deadline = time.monotonic() + self.window_s
-                while (len(self._pending) < self.max_batch
-                       and not self._closed):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._lock.wait(remaining)
-                window = self._pending
-                self._pending = []
+                with Stage("polytope.admission.collect"):
+                    deadline = time.monotonic() + self.window_s
+                    while (len(self._pending) < self.max_batch
+                           and not self._closed):
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            break
+                        self._lock.wait(remaining)
+                drained = time.perf_counter()
+                window, stamps = self._pending, self._stamps
+                self._pending, self._stamps = [], []
                 self.stats.submitted += len(window)
+                self.stats.wait_s += sum(drained - t for t in stamps)
                 self.stats.windows += 1
                 self.stats.window_max = max(self.stats.window_max,
                                             len(window))
-            self._serve_window(window)
+                n = self.stats.windows
+            with Stage("polytope.window", window=n, requests=len(window)):
+                self._serve_window(window)
 
     def _serve_window(self,
                       window: list[tuple[Request, Future]]) -> None:
