@@ -29,6 +29,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, fields, replace
 from typing import Any, Iterable, Sequence
 
+import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
@@ -64,6 +65,7 @@ class CacheStats:
     launch_time_s: float = 0.0      # gather call: trace, compile, dispatch
     copy_time_s: float = 0.0        # device wait + copy back to the host
     slice_time_s: float = 0.0       # per-request slices of the union
+    gather_padded_elems: int = 0    # launch length less union length
 
     @property
     def lookups(self) -> int:
@@ -502,6 +504,38 @@ class ExtractionService:
         return results
 
 
+# Launch lengths of a served union read: a power of two, at least this.
+# The floor keeps the small point and profile unions on one program.
+UNION_BUCKET_FLOOR = 1024
+
+
+def bucket(n: int) -> int:
+    """The smallest power of two ≥ ``max(n, UNION_BUCKET_FLOOR)``."""
+    return 1 << (max(n, UNION_BUCKET_FLOOR) - 1).bit_length()
+
+
+def padded_read_plan(union_plan: ExtractionPlan
+                     ) -> tuple[ExtractionPlan, int]:
+    """The union's plan at :func:`bucket` length, and where the union
+    starts in it.
+
+    ``jnp.take`` compiles once per index length, and nearly every
+    coalesced window's union has a length of its own; read at a bucket's
+    length, each bucket compiles once per process.  The padding repeats
+    the union's first and last offsets, in-range indices (an
+    out-of-range one would read as NaN), split so that the union sits in
+    the middle: the read's middle element is one the window asked for,
+    which a check that alters it (``bench/tests/test_correctness.py``)
+    then finds in an answer.  The runs stay the union's, which the
+    repeats add nothing to.
+    """
+    n = union_plan.n_points
+    pad = bucket(n) - n
+    lead = (pad + 1) // 2
+    offsets = np.pad(union_plan.offsets, (lead, pad - lead), mode="edge")
+    return replace(union_plan, offsets=offsets), lead
+
+
 def shared_union_gather(datacube: Datacube,
                         results: list[ServiceResult],
                         batch_plans: dict[str, ExtractionPlan],
@@ -512,7 +546,8 @@ def shared_union_gather(datacube: Datacube,
     result's values out of the shared buffer.
 
     Fills ``res.values`` in place and returns the batch's accounting as
-    a :class:`CacheStats` delta: bytes requested and read,
+    a :class:`CacheStats` delta: bytes requested and read (the union's,
+    without the launch's padding, counted in ``gather_padded_elems``),
     ``gather_time_s`` and its four stages (union build, gather launch,
     copy back, slicing), which share boundary timestamps and so sum to
     it.  The caller folds the delta into its own stats under its own
@@ -537,10 +572,18 @@ def shared_union_gather(datacube: Datacube,
             from repro.analysis.plan_check import verify_plan
 
             verify_plan(union_plan, datacube=datacube)
+    # A device payload's plain read launches at a bucket's length and is
+    # copied back whole (a device-side trim would compile per length);
+    # a host payload has no compile and the Pallas burst read has its
+    # own grid, so both read the union exactly.
     with Stage("polytope.gather.launch", delta, "launch_time_s",
                start=union_st.end) as st:
-        out = gather(flat_data, union_plan, use_kernel=use_kernel)
-    # One device→host copy of the union; every answer is sliced from it.
+        read_plan, lead = union_plan, 0
+        if not use_kernel and isinstance(flat_data, jax.Array):
+            read_plan, lead = padded_read_plan(union_plan)
+        out = gather(flat_data, read_plan, use_kernel=use_kernel)
+    delta.gather_padded_elems = read_plan.n_points - union_plan.n_points
+    # One device→host copy of the read; every answer is sliced from it.
     with Stage("polytope.gather.copy", delta, "copy_time_s",
                start=st.end) as st:
         buf = np.asarray(out)
@@ -548,7 +591,7 @@ def shared_union_gather(datacube: Datacube,
                start=st.end) as st:
         per_key: dict[str, Any] = {}
         for key, plan in nonempty.items():
-            idx = np.searchsorted(union, plan.offsets)
+            idx = lead + np.searchsorted(union, plan.offsets)
             per_key[key] = buf[idx]
         for res in results:
             if res.plan.n_points:
