@@ -5,7 +5,8 @@ on it, and what the host was doing while it sat idle.
 Device activity is the union of the op intervals on the ``XLA Ops``
 line of each ``/device:TPU:<n>`` plane.  Each op is credited to the XLA
 module (``XLA Modules`` line) whose interval holds its start.  Host
-activity is every event on the ``/host:CPU`` plane's thread lines.
+activity is every event on the ``/host:CPU`` plane's thread lines: JAX's
+own and the program's spans (``Stage`` in ``src/repro/serve``).
 """
 
 from __future__ import annotations
@@ -20,7 +21,6 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
-NO_HOST_EVENT = "(no host event)"
 TOP = 10
 
 
@@ -96,7 +96,17 @@ HOST_ACTIVITY = (
                             r"DevicePut|CopyToDevice")),
     ("dispatch", re.compile(r"PjitFunction|Execute|EnqueueProgram")),
 )
-UNTRACED = "host work with no trace event (planning, union build)"
+# What is left goes to the program's leaf spans, named exactly, innermost
+# first: a planner inside a lookup counts as the planner.  The root span,
+# ``polytope.window``, holds every stage of a window and would take all
+# that none of them covers, so it is no label.
+PROGRAM_SPANS = (
+    "polytope.planner.cold", "polytope.planner.delta",
+    "polytope.plan_cache.lookup", "polytope.gather.union",
+    "polytope.gather.launch", "polytope.gather.copy",
+    "polytope.gather.slice", "polytope.admission.collect",
+)
+UNTRACED = "host work outside every span"
 
 
 def _measure(iv: np.ndarray) -> float:
@@ -127,18 +137,30 @@ def _subtract(a: np.ndarray, b: np.ndarray) -> np.ndarray:
 
 
 def _attribute_gaps(gaps: np.ndarray, host) -> list:
-    """Idle seconds by what the host was doing (``HOST_ACTIVITY``)."""
+    """Idle seconds by what the host was doing: ``HOST_ACTIVITY``, then
+    ``PROGRAM_SPANS``, then ``UNTRACED``, largest first.  Labels that
+    cover no idle time are left out; past ``TOP`` labels, the smallest
+    are joined into one, so the seconds still sum to the idle time."""
+    by_name = defaultdict(list)
+    for name, start, dur in host:
+        by_name[name].append((start, start + dur))
+    rules = [(label, rx.search) for label, rx in HOST_ACTIVITY]
+    rules += [(span, span.__eq__) for span in PROGRAM_SPANS]
     out = []
     left = gaps
-    for label, rx in HOST_ACTIVITY:
-        ev = [h for h in host if rx.search(h[0])]
-        cover = union_intervals(np.array([h[1] for h in ev]),
-                                np.array([h[1] + h[2] for h in ev]))
-        hit = _intersect(left, cover)
-        out.append([label, _measure(hit) / 1e9])
+    for label, match in rules:
+        ev = np.array([se for name, ses in by_name.items() if match(name)
+                       for se in ses]).reshape(-1, 2)
+        cover = union_intervals(ev[:, 0], ev[:, 1])
+        out.append([label, _measure(_intersect(left, cover)) / 1e9])
         left = _subtract(left, cover)
     out.append([UNTRACED, _measure(left) / 1e9])
-    return sorted(out, key=lambda kv: -kv[1])
+    out = sorted((kv for kv in out if kv[1] > 0), key=lambda kv: -kv[1])
+    if len(out) > TOP:
+        rest = out[TOP - 1:]
+        out = out[:TOP - 1] + [[" + ".join(k for k, _ in rest),
+                                sum(s for _, s in rest)]]
+    return out
 
 
 def reduce_profile(profile, window_ns: tuple[float, float]) -> Reduced:

@@ -18,7 +18,38 @@ sys.path.insert(0, str(BENCH))
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 DATA = Path(__file__).resolve().parent / "data"
-TINY = {"o1280-oper-archive": "o32-tiny"}
+
+
+def stand_ins(*dirs: Path) -> dict[str, list[Path]]:
+    """Configuration name → the tiny stand-ins for it: the JSON files of
+    ``dirs`` whose key ``"stands_for"`` names it."""
+    found: dict[str, list[Path]] = {}
+    for d in dirs:
+        for f in sorted(Path(d).glob("*.json")):
+            name = json.loads(f.read_text()).get("stands_for")
+            if name:
+                found.setdefault(name, []).append(f)
+    return found
+
+
+def write_checkout(root: Path, bm: dict, dirs=(DATA,)) -> None:
+    """Write at ``root`` the traffic mixes and ``bm`` with every
+    configuration pointed at its one stand-in in ``dirs``; a
+    configuration with none, or with two, is named in the error."""
+    found = stand_ins(*dirs)
+    bm = json.loads(json.dumps(bm))
+    for c in bm["configs"]:
+        files = found.get(c["name"], [])
+        if len(files) != 1:
+            raise LookupError(
+                f"configuration {c['name']!r} needs one tiny stand-in, a "
+                f"JSON file in {', '.join(map(str, dirs))} with "
+                f"\"stands_for\": \"{c['name']}\"; found "
+                f"{[f.name for f in files]}")
+        c["file"] = str(files[0])
+    shutil.copytree(BENCH / "traffic", root / "bench" / "traffic",
+                    dirs_exist_ok=True)
+    (root / "BENCHMARK.json").write_text(json.dumps(bm))
 
 
 @pytest.fixture
@@ -27,12 +58,7 @@ def tiny_root(tmp_path, monkeypatch):
     tiny stand-in, with CPU 'peaks' and no look for a chip."""
     from harness import cell, spec
 
-    bm = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    (tmp_path / "bench").mkdir()
-    shutil.copytree(BENCH / "traffic", tmp_path / "bench" / "traffic")
-    for c in bm["configs"]:
-        c["file"] = str(DATA / f"{TINY[c['name']]}.json")
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bm))
+    write_checkout(tmp_path, spec.load_benchmark(BENCH.parent))
     monkeypatch.setattr(spec, "ROOT", tmp_path)
     monkeypatch.setattr(spec, "peaks", lambda kind: {
         "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9})

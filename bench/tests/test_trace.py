@@ -76,3 +76,48 @@ def test_device_ops_are_grouped_by_module_and_op(profile):
     names = [k for k, _ in r.device_ops]
     assert names[0] == "jit__take:%fusion"      # the gather itself
     assert len(names) == len(set(names))
+
+
+def synthetic(host_ms, ops_ms=((0, 10), (90, 100))):
+    """A trace of one TPU whose ops run over ``ops_ms`` and whose host
+    thread holds the events ``(name, start, end)``, in milliseconds."""
+    from types import SimpleNamespace as NS
+
+    def line(name, events):
+        return NS(name=name, events=[NS(name=n, start_ns=a * 1e6,
+                                        duration_ns=(b - a) * 1e6)
+                                     for n, a, b in events])
+
+    return NS(planes=[
+        NS(name="/device:TPU:0",
+           lines=[line(tr.OPS_LINE, [("%fusion", a, b) for a, b in ops_ms])]),
+        NS(name=tr.HOST_PLANE, lines=[line("worker", host_ms)])])
+
+
+def test_idle_gaps_name_the_innermost_program_stage():
+    r = tr.reduce_profile(synthetic([
+        ("polytope.window", 10, 80),
+        ("polytope.plan_cache.lookup", 10, 40),
+        ("polytope.planner.delta", 15, 35),
+        ("backend_compile", 20, 25),
+        ("polytope.admission.collect", 40, 60)]), (0.0, 100e6))
+    labels = dict(r.idle_gaps)
+    assert labels == pytest.approx({
+        "compile": 0.005, "polytope.planner.delta": 0.015,
+        "polytope.plan_cache.lookup": 0.010,
+        "polytope.admission.collect": 0.020, tr.UNTRACED: 0.030})
+    assert sum(labels.values()) == pytest.approx(r.window_s - r.busy_s)
+    assert "polytope.window" not in labels
+
+
+def test_idle_gaps_keep_ten_labels_and_every_second():
+    host = [("backend_compile", 10, 12), ("TransferToDevice", 12, 14),
+            ("PjitFunction(_take)", 14, 16)]
+    host += [(span, 16 + 4 * i, 20 + 4 * i)
+             for i, span in enumerate(tr.PROGRAM_SPANS)]
+    r = tr.reduce_profile(synthetic(host), (0.0, 100e6))
+    assert len(r.idle_gaps) == tr.TOP
+    assert sum(s for _, s in r.idle_gaps) == pytest.approx(0.080)
+    joined = r.idle_gaps[-1][0].split(" + ")
+    assert len(joined) == 3 and set(joined) <= {"compile", "transfer",
+                                                "dispatch"}
