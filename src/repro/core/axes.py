@@ -31,6 +31,10 @@ from typing import Any, Sequence
 
 import numpy as np
 
+# ``indices_in_range`` widens [lo, hi] by this much of the axis' largest
+# magnitude, so that bounds lying exactly on an index value are kept.
+RANGE_TOL = 1e-9
+
 
 class Axis:
     """Base axis: a named, discrete set of indices."""
@@ -98,8 +102,8 @@ class OrderedAxis(Axis):
         return float(self._to_float(np.asarray([value]))[0])
 
     # -- range query ----------------------------------------------------
-    def indices_in_range(self, lo: float, hi: float,
-                         tol: float = 1e-9) -> tuple[np.ndarray, np.ndarray]:
+    def indices_in_range(self, lo: float, hi: float, tol: float = RANGE_TOL
+                         ) -> tuple[np.ndarray, np.ndarray]:
         """Positions (storage order) and float values inside [lo, hi].
 
         ``tol`` (relative to axis span) widens the interval so that
@@ -142,8 +146,8 @@ class CyclicAxis(OrderedAxis):
         if base[-1] - base[0] >= self.period:
             raise ValueError("axis values must span < one period")
 
-    def indices_in_range(self, lo: float, hi: float,
-                         tol: float = 1e-9) -> tuple[np.ndarray, np.ndarray]:
+    def indices_in_range(self, lo: float, hi: float, tol: float = RANGE_TOL
+                         ) -> tuple[np.ndarray, np.ndarray]:
         if hi - lo >= self.period:  # whole circle requested
             pos = np.arange(len(self._sorted))
             if self._order is not None:

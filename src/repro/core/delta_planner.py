@@ -41,9 +41,11 @@ the device planner):
   period``) and the request window must stay below one period, so the
   seam-split index lookup is translation-equivariant;
 * a shifted non-cyclic, non-leading axis must keep both the old and
-  new request windows strictly interior to the axis value span (no
-  boundary clipping — clipping is only handled on the *leading* axis,
-  where the fresh/dropped slab machinery absorbs it);
+  new request windows inside the axis value span: a window may reach
+  the first or last value, but not pass it by more than the index
+  lookup's widening (past an edge cold planning clips positions — that
+  is only handled on the *leading* axis, where the fresh/dropped slab
+  machinery absorbs it);
 * select values on shifted axes must be numeric (labels don't
   translate).
 """
@@ -56,7 +58,7 @@ from typing import Any
 
 import numpy as np
 
-from .axes import CyclicAxis, OrderedAxis
+from .axes import RANGE_TOL, CyclicAxis, OrderedAxis
 from .datacube import Datacube, TensorDatacube, TransformedDatacube
 from .index_tree import ExtractionPlan, assemble_plan, flatten
 from .shapes import Request, _is_numeric
@@ -206,13 +208,12 @@ class DeltaPlanner:
                 if hi_o - lo_o >= limit or hi_n - lo_n >= limit:
                     return False
             elif ax != lead_name:
-                # interior check: neither window may clip at the axis
-                # boundary (2× the index-lookup widening tolerance)
-                eps = 2e-9 * info.scale
-                if not (lo_o >= info.values[0] + eps
-                        and hi_o <= info.values[-1] - eps
-                        and lo_n >= info.values[0] + eps
-                        and hi_n <= info.values[-1] - eps):
+                # a window reaching an edge value clips nothing; one past
+                # it by more than the lookup's widening loses positions
+                # that the shifted parent would keep
+                eps = RANGE_TOL * info.scale
+                if (min(lo_o, lo_n) < info.values[0] - eps
+                        or max(hi_o, hi_n) > info.values[-1] + eps):
                     return False
         return True
 
@@ -403,7 +404,7 @@ class DeltaPlanner:
         Valid because the layout is a mixed-radix number system (the
         regular-cube eligibility): position on axis ``ax`` is
         ``(off // stride) % size`` and per-axis digit updates never
-        carry — non-cyclic shifts stay in range by the interior /
+        carry — non-cyclic shifts stay in range by the edge /
         correspondence checks, cyclic shifts wrap within the digit.
         """
         if len(offs) == 0:

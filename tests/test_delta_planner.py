@@ -44,8 +44,10 @@ def planner(wcube, extractor):
 
 def lon_box(lon_lo, lon_hi, lat_lo=20.0, lat_hi=70.0, datetime=0.0,
             level=1.0):
-    return Request([Select("datetime", [datetime]),
-                    Select("level", [level]),
+    """A box at one datetime; ``level`` a value, or ``(lo, hi)`` a span."""
+    lev = (Span("level", *level) if isinstance(level, tuple)
+           else Select("level", [level]))
+    return Request([Select("datetime", [datetime]), lev,
                     Box(("lat", "lon"), [lat_lo, lon_lo],
                         [lat_hi, lon_hi])])
 
@@ -176,6 +178,21 @@ class TestSpliceByteIdentity:
         splice_or_fail(planner, extractor, window_req(0.0),
                        window_req(3 * DT_STEP), wcube.cube)
 
+    @pytest.mark.parametrize("old, new", [
+        (1.0, 0.0), (2.0, 3.0),
+        ((1.0, 2.0), (0.0, 1.0)), ((1.0, 2.0), (2.0, 3.0)),
+        ((0.0, 1.0), (2.0, 3.0))],
+        ids=["select-onto-first", "select-onto-last", "span-onto-first",
+             "span-onto-last", "span-first-to-last"])
+    def test_level_drift_onto_the_edge(self, planner, extractor, wcube,
+                                       old, new):
+        # a window that reaches the first or last level clips nothing,
+        # so the drift splices to the cold plan's bytes
+        shifts = splice_or_fail(planner, extractor,
+                                lon_box(34.0, 76.0, level=old),
+                                lon_box(34.0, 76.0, level=new), wcube.cube)
+        assert set(shifts) == {"level"}
+
     def test_lead_select_drift(self, planner, extractor, wcube):
         splice_or_fail(planner, extractor,
                        lon_box(34.0, 76.0, datetime=0.0),
@@ -217,16 +234,31 @@ class TestSpliceByteIdentity:
 
 
 class TestFallbackTransparency:
-    def test_boundary_level_select_falls_back(self, planner, extractor):
-        # shifted non-lead, non-cyclic axes need both windows interior;
-        # level 0 sits on the axis edge, so the drift must plan cold
-        r_old = lon_box(34.0, 76.0, level=0.0)
-        r_new = lon_box(34.0, 76.0, level=1.0)
+    @staticmethod
+    def assert_falls_back(planner, extractor, r_old, r_new):
         shifts = planner.axis_shifts(r_old.shape_signature()[1],
                                      r_new.shape_signature()[1])
-        assert shifts is not None
+        assert shifts is not None and set(shifts) == {"level"}
         p, s = extractor.plan(r_old)
         assert planner.splice(r_new, r_old, p, s, shifts) is None
+
+    def test_boundary_level_select_falls_back(self, planner, extractor):
+        # levels are 0..3: a select drifted past the last level snaps
+        # back to it when planned cold, so the shifted parent is wrong
+        self.assert_falls_back(planner, extractor,
+                               lon_box(34.0, 76.0, level=3.0),
+                               lon_box(34.0, 76.0, level=4.0))
+
+    @pytest.mark.parametrize("old, new", [
+        ((2.0, 3.0), (3.0, 4.0)), ((0.0, 1.0), (-1.0, 0.0))],
+        ids=["past-last", "past-first"])
+    def test_level_span_past_the_edge_falls_back(self, planner, extractor,
+                                                 old, new):
+        # cold planning clips the window at the edge and loses a level
+        # that the shifted parent would keep
+        self.assert_falls_back(planner, extractor,
+                               lon_box(34.0, 76.0, level=old),
+                               lon_box(34.0, 76.0, level=new))
 
     def test_near_full_circle_cyclic_falls_back(self, planner, extractor):
         # a lon window wider than period − step can alias across the
