@@ -186,6 +186,16 @@ def coalesce_runs(offsets: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         np.int64)
 
 
+def expand_runs(starts: np.ndarray, lengths: np.ndarray) -> np.ndarray:
+    """Inverse of :func:`coalesce_runs`: the offsets of ``(start,
+    length)`` runs, in run order (one repeat plus a ramp)."""
+    if len(lengths) == 0:
+        return np.empty(0, np.int64)
+    ends = np.cumsum(lengths)
+    return (np.repeat(starts - (ends - lengths), lengths)
+            + np.arange(int(ends[-1]), dtype=np.int64))
+
+
 @dataclass
 class CompressedPlan:
     """Delta-encoded int32 plan: the wire/cache form of the run list.
@@ -255,11 +265,7 @@ def decompress_plan(cp: CompressedPlan) -> ExtractionPlan:
                               itemsize=cp.itemsize)
     starts = (cp.base + np.cumsum(cp.start_gaps.astype(np.int64))
               + np.concatenate([[0], np.cumsum(lengths[:-1])]))
-    ends = np.cumsum(lengths)
-    total = int(ends[-1])
-    offsets = (np.repeat(starts, lengths)
-               + np.arange(total, dtype=np.int64)
-               - np.repeat(ends - lengths, lengths))
-    return ExtractionPlan(offsets=offsets, run_starts=starts,
+    return ExtractionPlan(offsets=expand_runs(starts, lengths),
+                          run_starts=starts,
                           run_lengths=lengths, coords={},
                           itemsize=cp.itemsize)

@@ -177,7 +177,7 @@ def run_extract(args, payload=None):
           f"{s.launch_time_s * per_win:.2f}ms, copy "
           f"{s.copy_time_s * per_win:.2f}ms, slice "
           f"{s.slice_time_s * per_win:.2f}ms; launch padding "
-          f"{s.gather_padded_elems} elements")
+          f"{s.gather_padded_elems} elements, union runs {s.union_runs}")
     print(f"wrote {args.bench_out}")
     return row, [a for per in answers for a in per]
 

@@ -36,7 +36,7 @@ from jax.profiler import TraceAnnotation
 from repro.core import PolytopeExtractor, Request, gather
 from repro.core.datacube import Datacube
 from repro.core.delta_planner import DeltaPlanner
-from repro.core.index_tree import ExtractionPlan, coalesce_runs
+from repro.core.index_tree import ExtractionPlan, expand_runs
 from repro.core.shapes import CANON_TOL
 from repro.core.slicer import SliceStats
 
@@ -66,6 +66,7 @@ class CacheStats:
     copy_time_s: float = 0.0        # device wait + copy back to the host
     slice_time_s: float = 0.0       # per-request slices of the union
     gather_padded_elems: int = 0    # launch length less union length
+    union_runs: int = 0             # runs of the windows' merged unions
 
     @property
     def lookups(self) -> int:
@@ -536,6 +537,52 @@ def padded_read_plan(union_plan: ExtractionPlan
     return replace(union_plan, offsets=offsets), lead
 
 
+def union_of_runs(plans: Sequence[ExtractionPlan],
+                  itemsize: int) -> ExtractionPlan:
+    """The union of ``plans`` (non-empty, each tiled by its runs) as one
+    plan, built from their runs and not their elements: the runs in
+    order of start, those that overlap or touch merged into one, the
+    offsets expanded from the merged runs.  Byte-identical to
+    ``coalesce_runs(np.unique(<every plan's offsets>))``.  Runs that
+    already come ascending and apart, as a window's only plan's do, are
+    the union as they stand, and the plans' offsets end to end are its
+    offsets."""
+    starts = np.concatenate([p.run_starts for p in plans])
+    lengths = np.concatenate([p.run_lengths for p in plans])
+    ends = starts + lengths
+    if np.all(starts[1:] > ends[:-1]):
+        offsets = (plans[0].offsets if len(plans) == 1
+                   else np.concatenate([p.offsets for p in plans]))
+    else:
+        order = np.argsort(starts, kind="stable")
+        starts, ends = starts[order], ends[order]
+        # a run opens a merged run where it starts past every earlier end
+        reach = np.maximum.accumulate(ends)
+        heads = np.flatnonzero(np.r_[True, starts[1:] > reach[:-1]])
+        lengths = reach[np.r_[heads[1:], len(starts)] - 1] - starts[heads]
+        starts = starts[heads]
+        offsets = expand_runs(starts, lengths)
+    return ExtractionPlan(offsets=offsets, run_starts=starts,
+                          run_lengths=lengths, coords={}, itemsize=itemsize)
+
+
+def slice_by_runs(buf: np.ndarray, run_pos: np.ndarray,
+                  union_plan: ExtractionPlan,
+                  plan: ExtractionPlan) -> np.ndarray:
+    """``plan``'s answer out of ``buf``, which holds ``union_plan``'s
+    elements, its i-th run from ``run_pos[i]`` on.  Each run of the plan
+    lies inside one run of the union, found by its start, so the plan's
+    positions come from its runs.  Where they form one stretch the
+    answer is a copy of it, never a view: a view would keep the whole
+    read buffer alive for as long as the answer."""
+    u = np.searchsorted(union_plan.run_starts, plan.run_starts,
+                        side="right") - 1
+    pos = run_pos[u] + (plan.run_starts - union_plan.run_starts[u])
+    if np.array_equal(pos[1:], pos[:-1] + plan.run_lengths[:-1]):
+        return buf[pos[0]:pos[0] + plan.n_points].copy()
+    return buf[expand_runs(pos, plan.run_lengths)]
+
+
 def shared_union_gather(datacube: Datacube,
                         results: list[ServiceResult],
                         batch_plans: dict[str, ExtractionPlan],
@@ -550,10 +597,10 @@ def shared_union_gather(datacube: Datacube,
     without the launch's padding, counted in ``gather_padded_elems``),
     ``gather_time_s`` and its four stages (union build, gather launch,
     copy back, slicing), which share boundary timestamps and so sum to
-    it.  The caller folds the delta into its own stats under its own
-    lock.  Shared between :class:`ExtractionService` and the sharded
-    service in :mod:`repro.serve.sharded` — both funnel a window's
-    distinct plans through exactly one gather.
+    it, and the union's runs.  The caller folds the delta into its own
+    stats under its own lock.  Shared between :class:`ExtractionService`
+    and the sharded service in :mod:`repro.serve.sharded` — both funnel
+    a window's distinct plans through exactly one gather.
     """
     delta = CacheStats()
     nonempty = {k: p for k, p in batch_plans.items() if p.n_points}
@@ -562,12 +609,8 @@ def shared_union_gather(datacube: Datacube,
             res.values = np.empty(0, datacube.dtype)
         return delta
     with Stage("polytope.gather.union", delta, "union_time_s") as union_st:
-        union = np.unique(np.concatenate(
-            [p.offsets for p in nonempty.values()]))
-        starts, lengths = coalesce_runs(union)
-        union_plan = ExtractionPlan(
-            offsets=union, run_starts=starts, run_lengths=lengths,
-            coords={}, itemsize=datacube.dtype.itemsize)
+        union_plan = union_of_runs(list(nonempty.values()),
+                                   datacube.dtype.itemsize)
         if verify:
             from repro.analysis.plan_check import verify_plan
 
@@ -589,10 +632,10 @@ def shared_union_gather(datacube: Datacube,
         buf = np.asarray(out)
     with Stage("polytope.gather.slice", delta, "slice_time_s",
                start=st.end) as st:
-        per_key: dict[str, Any] = {}
-        for key, plan in nonempty.items():
-            idx = lead + np.searchsorted(union, plan.offsets)
-            per_key[key] = buf[idx]
+        lengths = union_plan.run_lengths
+        run_pos = lead + np.cumsum(lengths) - lengths
+        per_key = {key: slice_by_runs(buf, run_pos, union_plan, plan)
+                   for key, plan in nonempty.items()}
         for res in results:
             if res.plan.n_points:
                 res.values = per_key[res.key]
@@ -600,5 +643,6 @@ def shared_union_gather(datacube: Datacube,
                 res.values = np.empty(0, datacube.dtype)
             delta.bytes_requested += res.plan.nbytes
     delta.bytes_read = union_plan.nbytes
+    delta.union_runs = union_plan.n_runs
     delta.gather_time_s = st.end - union_st.start
     return delta
