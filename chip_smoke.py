@@ -54,7 +54,6 @@ from repro.launch.serve import (build_parser, load_payload,  # noqa: E402
 # WeatherCube(n=1280, n_times=8, n_levels=20): paper Table 1's archive.
 GRID_N, N_TIMES, N_LEVELS = 1280, 8, 20
 REQUESTS, THREADS, SHARDS = 64, 4, 4
-SERVE_OUT = ROOT / "chiprun_out" / "chip_smoke_serve.json"
 
 
 def note(msg: str) -> None:
@@ -92,13 +91,11 @@ def place_payload(wc: WeatherCube, seed: int):
 
 
 def serve_requests(seed: int, host: np.ndarray, payload) -> list:
-    SERVE_OUT.parent.mkdir(exist_ok=True)
     args = build_parser().parse_args([
         "--mode", "extract", "--grid-n", str(GRID_N),
         "--n-times", str(N_TIMES), "--n-levels", str(N_LEVELS),
         "--requests", str(REQUESTS), "--threads", str(THREADS),
-        "--shards", str(SHARDS), "--seed", str(seed),
-        "--bench-out", str(SERVE_OUT)])
+        "--shards", str(SHARDS), "--seed", str(seed)])
     t0 = time.perf_counter()
     _, answers = run_extract(args, payload)
     if len(answers) != REQUESTS:

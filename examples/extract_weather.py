@@ -3,13 +3,9 @@ a synthetic weather cube, printing the index-tree → plan → gather flow —
 plus the irregular-datacube scenario (DESIGN.md §2.5): merged date/time,
 mapped Gaussian latitudes, and a cross-seam UK crop on a cyclic
 longitude, served through the plan cache with a seam-shifted cache hit.
-Emits ``BENCH_extraction.json`` with the irregular scenario's reduction
-factor, plan time, and bytes moved.
 
   PYTHONPATH=src python examples/extract_weather.py
 """
-
-import json
 
 import numpy as np
 
@@ -17,7 +13,7 @@ from repro.core import (BoundingBoxExtractor, Box, PolytopeExtractor,
                         Request, Select, Slicer, TraditionalExtractor)
 from repro.dataplane.weather import (COUNTRIES, IrregularWeatherCube,
                                      WeatherCube, paris_newyork_path)
-from repro.serve.extraction import ExtractionService
+from repro.serve.sharded import ShardedExtractionService
 
 
 def irregular_scenarios(iwc: IrregularWeatherCube) -> dict:
@@ -29,37 +25,28 @@ def irregular_scenarios(iwc: IrregularWeatherCube) -> dict:
     }
 
 
-def run_irregular(out_path: str = "BENCH_extraction.json") -> None:
+def run_irregular() -> None:
     print("— irregular datacube (merged datetime · mapped Gaussian lat · "
           "cyclic lon) —")
     iwc = IrregularWeatherCube(n_lat=160, n_lon=320)
     data = iwc.field_data(seed=3)
-    svc = ExtractionService(iwc.cube)
+    svc = ShardedExtractionService(iwc.cube, shards=1)
     bb = BoundingBoxExtractor(iwc.cube)
     tr = TraditionalExtractor(iwc.cube, field_axes=("lat", "lon"))
     print(f"cube: {iwc.cube.n_elements:,} elements, logical axes "
           f"{iwc.cube.axis_names}, periods {iwc.cube.axis_periods()}\n")
 
-    rows = []
     for name, req in irregular_scenarios(iwc).items():
         res = svc.extract(req, data)
         plan, stats = res.plan, res.stats
-        trad = tr.nbytes(req)
-        box = bb.plan(req).nbytes
-        rows.append(dict(
-            example=name,
-            polytope_bytes=int(plan.nbytes),
-            bbox_bytes=int(box),
-            traditional_bytes=int(trad),
-            n_points=plan.n_points,
-            n_runs=plan.n_runs,
-            reduction_vs_traditional=trad / max(plan.nbytes, 1),
-            reduction_vs_bbox=box / max(plan.nbytes, 1),
-            plan_time_s=stats.total_time_s if stats else 0.0,
-        ))
+        nbytes = max(plan.nbytes, 1)
+        plan_ms = stats.total_time_s * 1e3 if stats else 0.0
         print(f"{name}: {plan.n_points} points, {plan.nbytes:,} B in "
-              f"{plan.n_runs} runs, reduction {trad / max(plan.nbytes, 1):,.0f}× "
-              f"vs whole-field, values mean {float(np.mean(res.values)):.2f}")
+              f"{plan.n_runs} runs, reduction "
+              f"{tr.nbytes(req) / nbytes:,.0f}× vs whole-field and "
+              f"{bb.plan(req).nbytes / nbytes:,.1f}× "
+              f"vs bounding box, planned in {plan_ms:.1f} ms, "
+              f"values mean {float(np.mean(res.values)):.2f}")
 
     # Seam-shifted re-request: same geometry expressed +360° away must
     # hit the plan cache (canonicalization modulo the period).
@@ -68,13 +55,7 @@ def run_irregular(out_path: str = "BENCH_extraction.json") -> None:
     base = iwc.seam_box_request(40.0, 60.0, -20.0, 20.0)
     svc.extract(base)
     hit = svc.extract(shifted)
-    print(f"seam-shifted box (+360°) served from cache: {hit.cached}\n")
-
-    payload = {"bench": "extraction", "rows": rows,
-               "seam_shift_cache_hit": bool(hit.cached)}
-    with open(out_path, "w") as fh:
-        json.dump(payload, fh, indent=2)
-    print(f"wrote {out_path}")
+    print(f"seam-shifted box (+360°) served from cache: {hit.cached}")
 
 
 def main() -> None:

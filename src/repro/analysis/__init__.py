@@ -1,4 +1,4 @@
-# Static verification layer (DESIGN.md §6): machine-checks the contracts
+# Static verification layer (DESIGN.md §5): machine-checks the contracts
 # the rest of the repo states in prose.  Three analyzers, all pure and
 # dependency-light (numpy + ast only — importing this package never pulls
 # jax), runnable as `python -m repro.analysis` and as a pytest tier:
@@ -11,7 +11,6 @@
 #                unguarded int32 casts on offset-carrying arrays)
 # concurrency  — lock-discipline race detector (attributes written under
 #                `with self._lock` must not be touched outside it)
-from .bench_schema import check_bench_file
 from .concurrency import check_lock_discipline, check_lock_source
 from .diagnostics import Diagnostic
 from .lint import lint_source, lint_tree
@@ -22,5 +21,4 @@ __all__ = [
     "PlanVerificationError", "check_plan", "verify_plan",
     "lint_source", "lint_tree",
     "check_lock_discipline", "check_lock_source",
-    "check_bench_file",
 ]

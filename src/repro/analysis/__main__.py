@@ -1,17 +1,16 @@
-"""CLI for the static verification layer (DESIGN.md §6).
+"""CLI for the static verification layer (DESIGN.md §5).
 
     python -m repro.analysis --all            # the CI gate
     python -m repro.analysis --lint --locks   # source analyzers only
     python -m repro.analysis --plan p.pkl     # verify a pickled plan
-    python -m repro.analysis --bench BENCH_extraction.json
 
 Exits non-zero on any diagnostic.  ``--all`` runs the lint, the
-lock-discipline checker, the bench schema check (when the file exists)
-and a planner self-check: a handful of real plans built against small
-cubes, each required to verify clean — so the gate exercises
-``plan_check`` against live planner output, not just fixtures.
+lock-discipline checker and a planner self-check: a handful of real
+plans built against small cubes, each required to verify clean — so the
+gate exercises ``plan_check`` against live planner output, not just
+fixtures.
 
-Source analyzers are pure ast/json and never import jax; only the
+Source analyzers are pure ast and never import jax; only the
 ``--self-check`` path imports the planner.
 """
 
@@ -21,7 +20,6 @@ import argparse
 import sys
 from pathlib import Path
 
-from .bench_schema import check_bench_file
 from .concurrency import check_lock_discipline
 from .diagnostics import Diagnostic, render
 from .lint import lint_tree
@@ -66,20 +64,15 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.analysis",
         description="Static verification layer: plan checker, AST lint, "
-                    "lock-discipline race detector, bench schema check.")
+                    "lock-discipline race detector.")
     ap.add_argument("--all", action="store_true",
-                    help="run lint + locks + bench + planner self-check "
+                    help="run lint + locks + planner self-check "
                          "(the CI gate)")
     ap.add_argument("--lint", action="store_true", help="AST lint rules")
     ap.add_argument("--locks", action="store_true",
                     help="lock-discipline checker")
     ap.add_argument("--self-check", action="store_true",
                     help="verify live planner output on small cubes")
-    ap.add_argument("--bench", nargs="*", metavar="JSON",
-                    help="bench files to schema-check (default: "
-                         "BENCH_extraction.json / BENCH_serve.json / "
-                         "BENCH_kernels.json / BENCH_delta.json "
-                         "when present)")
     ap.add_argument("--plan", nargs="*", metavar="PKL", default=[],
                     help="pickled ExtractionPlan files to verify")
     ap.add_argument("--n-elements", type=int, default=None,
@@ -99,16 +92,6 @@ def main(argv: list[str] | None = None) -> int:
     if args.all or args.locks:
         ran = True
         diags += check_lock_discipline(src_root)
-    bench_files = list(args.bench or [])
-    if args.all and not bench_files:
-        for name in ("BENCH_extraction.json", "BENCH_serve.json",
-                     "BENCH_kernels.json", "BENCH_delta.json"):
-            default_bench = Path.cwd() / name
-            if default_bench.exists():
-                bench_files.append(default_bench)
-    for bf in bench_files or []:
-        ran = True
-        diags += check_bench_file(bf)
     for pf in args.plan:
         ran = True
         diags += check_plan_file(pf, n_elements=args.n_elements)

@@ -1,4 +1,4 @@
-"""Lock-discipline race detector (DESIGN.md §6).
+"""Lock-discipline race detector (DESIGN.md §5).
 
 A static checker for the threaded serving layer
 (``serve/extraction.py``, ``dataplane/pipeline.py`` — and anything else

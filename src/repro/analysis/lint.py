@@ -1,4 +1,4 @@
-"""Repo-specific AST lint (DESIGN.md §6).
+"""Repo-specific AST lint (DESIGN.md §5).
 
 Three rules, each encoding a contract the design doc states in prose:
 

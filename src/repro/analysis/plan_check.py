@@ -21,7 +21,7 @@ states the invariants as code:
 
 Everything here is duck-typed over the plan/datacube attributes and
 imports nothing from ``repro`` — the checker stays importable without
-jax and free of circular imports, so ``Slicer``/``ExtractionService``
+jax and free of circular imports, so ``Slicer`` and the service
 can call it lazily under ``verify=True``.
 """
 

@@ -1,4 +1,4 @@
-"""Incremental delta planning for drifting polytopes (DESIGN.md §8).
+"""Incremental delta planning for drifting polytopes (DESIGN.md §6).
 
 Production request streams *drift*: the same flight corridor advanced
 one timestep, the same country crop for the next forecast cycle.  The

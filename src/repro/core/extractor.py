@@ -2,8 +2,8 @@
 
 * :class:`PolytopeExtractor` — the paper's technique: plan with the
   slicer, then read only the planned bytes.  On device the read is a
-  sharded gather (``jnp.take``) or the Pallas scalar-prefetch DMA kernel
-  (``repro.kernels.gather``) over coalesced runs.
+  ``jnp.take``; :func:`gather` also reads coalesced runs as bursts, or
+  through the Pallas scalar-prefetch DMA kernel (``repro.kernels.gather``).
 * :class:`BoundingBoxExtractor` — the "state of practice" baseline: the
   tensor-product box of the per-axis extents.
 * :class:`TraditionalExtractor` — whole-field reads (paper Table 1
@@ -41,17 +41,11 @@ class PolytopeExtractor:
     """Plan on host (float64 geometry) or on device (the fused
     ``device_planner`` pipeline), gather on host or device."""
 
-    def __init__(self, datacube: Datacube, use_kernel: bool = False,
-                 verify: bool = False, device_planner: bool = False,
-                 burst_gather: bool = False):
+    def __init__(self, datacube: Datacube, verify: bool = False,
+                 device_planner: bool = False):
         self.datacube = datacube
         self.slicer = Slicer(datacube, verify=verify,
                              device_planner=device_planner)
-        self.use_kernel = use_kernel
-        # burst_gather=True reads coalesced plan runs as wide contiguous
-        # DMA copies (kernels.gather.gather_plan_runs) instead of
-        # per-element loads — the bandwidth-bound warm path.
-        self.burst_gather = burst_gather
 
     def plan(self, request: Request) -> tuple[ExtractionPlan, SliceStats]:
         return self.slicer.extract_plan(request)
@@ -61,8 +55,7 @@ class PolytopeExtractor:
         plan, stats = self.plan(request)
         values = None
         if flat_data is not None:
-            values = gather(flat_data, plan, use_kernel=self.use_kernel,
-                            burst=self.burst_gather)
+            values = gather(flat_data, plan)
         return ExtractResult(values=values, plan=plan, stats=stats)
 
 

@@ -151,7 +151,7 @@ def shape_signature(polys: Sequence[Polytope], selects: Sequence["Select"],
     other — the same flight corridor advanced one timestep, the same
     country crop for the next forecast cycle — therefore share a
     signature while their anchors differ by the drift vector.  The
-    neighborhood index (DESIGN.md §8) keys on the signature hash and
+    neighborhood index (DESIGN.md §6) keys on the signature hash and
     stores anchors separately, so a drifted request resolves to its
     parent plan and only the drift delta remains to be applied.
 

@@ -96,7 +96,7 @@ class Slicer:
         self.fast_paths = fast_paths
         # verify=True runs the static plan checker
         # (repro.analysis.plan_check) over every emitted plan and raises
-        # on any violated invariant — the runtime hook of DESIGN.md §6.
+        # on any violated invariant — the runtime hook of DESIGN.md §5.
         self.verify = verify
         # device_planner=True routes eligible requests through the fused
         # on-device pipeline (repro.core.device_planner), which emits

@@ -6,9 +6,9 @@ accelerator never waits on the planner.  Step-addressable sources make
 fault-tolerant replay deterministic (``repro.train.fault``).
 
 :class:`CachedExtractionSource` routes a step's polytope requests
-through a shared :class:`~repro.serve.extraction.ExtractionService`, so
-recurring request geometry across steps is served from the plan cache
-instead of re-running Algorithm 1 (DESIGN.md §4).
+through a shared :class:`~repro.serve.sharded.ShardedExtractionService`,
+so recurring request geometry across steps is served from the plan
+cache instead of re-running Algorithm 1 (DESIGN.md §4).
 """
 
 from __future__ import annotations

@@ -5,7 +5,7 @@ a Polytope extraction: a box request over a document range × position
 window, planned by the slicer and gathered with the exact-byte path.
 Sharded loading: each data-parallel host plans and reads only its batch
 rows (plan-first ethos end-to-end).  All rows of a batch are submitted
-as one :class:`~repro.serve.extraction.ExtractionService` batch, so
+as one :class:`~repro.serve.sharded.ShardedExtractionService` batch, so
 duplicate windows plan once and recurring windows across steps/epochs
 hit the plan cache (DESIGN.md §4).
 
@@ -40,13 +40,14 @@ class TokenCube:
                                                 dtype=float))
         self.cube = TensorDatacube([doc_axis, pos_axis],
                                    dtype=np.dtype(np.int32))
-        from repro.serve.extraction import ExtractionService
+        from repro.serve.sharded import ShardedExtractionService
 
         # Random windows mostly miss the cache in normal training; the
         # cache pays off on exact-step replay (fault-tolerant restore)
         # and epoch revisits, so keep it small — plans are per-row and
         # cheap to rebuild.
-        self.service = ExtractionService(self.cube, capacity=512)
+        self.service = ShardedExtractionService(self.cube, shards=1,
+                                                capacity_per_shard=512)
 
     def _doc(self, doc_id: int) -> np.ndarray:
         rng = np.random.default_rng(self.seed * 100_003 + doc_id)

@@ -260,8 +260,7 @@ def request_population(wc: WeatherCube,
     """Ranked serving-mix population: country crops × time/level, spot
     time-series, vertical profiles.  Zipf-sampling over this list makes
     a few crops hot — the repetitive production stream the plan cache
-    (DESIGN.md §4) targets; used by ``launch/serve.py --mode extract``
-    and ``benchmarks/bench_plan_cache.py``."""
+    (DESIGN.md §4) targets; used by ``launch/serve.py --mode extract``."""
     population = []
     for name in COUNTRIES:
         for t in (0.0, 3600.0):

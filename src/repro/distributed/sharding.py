@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 # ---------------------------------------------------------------------------
-# Consistent-hash routing (DESIGN.md §7)
+# Consistent-hash routing (DESIGN.md §4)
 #
 # Plan-cache keys are stable sha256 content hashes
 # (``Request.canonical_hash``), so the routing point is simply the key's

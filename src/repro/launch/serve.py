@@ -73,16 +73,15 @@ def load_payload(wc, seed: int):
 def run_extract(args, payload=None):
     """Closed-loop Zipfian load against the sharded service: ``--threads``
     clients submit through one :class:`AdmissionQueue` (so duplicate hot
-    crops coalesce across callers inside each arrival window), and the
-    per-request latency distribution lands in ``--bench-out``.
+    crops coalesce across callers inside each arrival window), and a
+    summary of the run is printed.
 
     The payload is read on the device: ``payload`` is the device array
     of ``load_payload`` for the same cube, or ``None`` to build it here.
-    Returns ``(row, answers)``: the bench row and every answered
+    Returns ``(row, answers)``: the summary's figures and every answered
     ``ServiceResult`` in submission order per client.  Raises
     :class:`ClientError` if any client thread raised.
     """
-    import json
     import threading
 
     from repro.dataplane.weather import WeatherCube, request_population
@@ -140,24 +139,15 @@ def run_extract(args, payload=None):
                           f"threads failed: {failed[0]!r}") from failed[0]
 
     lat_ms = np.concatenate(latencies) * 1e3
-    if not len(lat_ms):  # --requests 0: an empty but schema-valid row
+    if not len(lat_ms):  # --requests 0: no latency to take a percentile of
         lat_ms = np.zeros(1)
     s = svc.stats
     row = {
-        "scenario": f"zipf{args.zipf_s}-grid{args.grid_n}",
         "requests": int(len(ranks)),
-        "threads": int(len(per_thread)),
-        "shards": int(args.shards),
-        "window_ms": float(args.window_ms),
         "p50_ms": float(np.percentile(lat_ms, 50)),
         "p99_ms": float(np.percentile(lat_ms, 99)),
         "req_per_s": float(len(ranks) / dt) if dt else 0.0,
-        "hit_rate": float(s.hit_rate),
-        "coalescing_factor": float(adm.coalescing_factor),
     }
-    with open(args.bench_out, "w") as fh:
-        json.dump({"bench": "serve", "rows": [row]}, fh, indent=1)
-
     print(f"served {len(ranks)} requests from {len(per_thread)} threads "
           f"in {dt:.2f}s ({row['req_per_s']:.0f} req/s)")
     print(f"latency p50 {row['p50_ms']:.2f}ms / p99 {row['p99_ms']:.2f}ms")
@@ -178,7 +168,6 @@ def run_extract(args, payload=None):
           f"{s.copy_time_s * per_win:.2f}ms, slice "
           f"{s.slice_time_s * per_win:.2f}ms; launch padding "
           f"{s.gather_padded_elems} elements, union runs {s.union_runs}")
-    print(f"wrote {args.bench_out}")
     return row, [a for per in answers for a in per]
 
 
@@ -199,7 +188,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--cache-capacity", type=int, default=256)
     ap.add_argument("--zipf-s", type=float, default=1.3)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--bench-out", default="BENCH_serve.json")
     return ap
 
 

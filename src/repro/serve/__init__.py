@@ -4,8 +4,7 @@
 # `from repro.serve.engine import ...` as a module path.
 import importlib
 
-from .extraction import (CacheStats, ExtractionService,  # noqa: F401
-                         PlanCache, ServiceResult)
+from .extraction import CacheStats, PlanCache, ServiceResult  # noqa: F401
 
 # sharded pulls distributed.sharding (jax) — lazy keeps the light half light
 _LAZY = ("engine", "kv_cache", "sharded")

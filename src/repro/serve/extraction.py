@@ -1,21 +1,25 @@
-"""Extraction service: plan caching + batched request serving (DESIGN.md §4).
+"""The building blocks of the extraction service (DESIGN.md §4): plan
+caching, span-and-counter stages, and the shared union read.
+:class:`repro.serve.sharded.ShardedExtractionService` assembles them.
 
 Production request streams against a datacube are highly repetitive —
 the same country crop every forecast cycle, the same recsys region every
 step, the same flight corridor for every flight on a route.  Re-running
 Algorithm 1 per request makes *planning*, not I/O, the bottleneck at
-scale.  This layer:
+scale.  This module holds:
 
-* keys every request by its canonical content hash
-  (``Request.canonical_hash``) so permuted-but-equivalent requests
-  collide;
-* serves :class:`~repro.core.index_tree.ExtractionPlan` objects from a
-  bounded LRU (:class:`PlanCache`) with hit/miss/eviction counters
-  exposed like ``SliceStats``;
-* dedupes concurrent requests inside a batch (plan once, share the
-  plan object);
-* executes all cache-missed gathers of a batch through one shared
-  coalesced-run union read, so overlapping requests read each byte once.
+* :class:`PlanCache`, a bounded LRU of
+  :class:`~repro.core.index_tree.ExtractionPlan` objects keyed by the
+  request's canonical content hash (``Request.canonical_hash``), so
+  permuted-but-equivalent requests collide, with hit/miss/eviction
+  counters in :class:`CacheStats` exposed like ``SliceStats``;
+* :class:`NeighborhoodIndex`, which finds a drifted request's parent
+  plan for the delta planner;
+* :class:`Stage`, one stage of the served path as a profiler span and a
+  counter at once;
+* :func:`shared_union_gather`, which executes a window's distinct plans
+  through one coalesced-run union read, so overlapping requests read
+  each byte once.
 
 Plans are immutable once built, so cache hits return the *same* plan
 object — byte-identical offsets to the cold plan by construction.
@@ -33,11 +37,9 @@ import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.core import PolytopeExtractor, Request, gather
+from repro.core import Request, gather
 from repro.core.datacube import Datacube
-from repro.core.delta_planner import DeltaPlanner
 from repro.core.index_tree import ExtractionPlan, expand_runs
-from repro.core.shapes import CANON_TOL
 from repro.core.slicer import SliceStats
 
 
@@ -333,178 +335,6 @@ class ServiceResult:
     stats: SliceStats | None = None
 
 
-class ExtractionService:
-    """Many concurrent polytope requests → deduped, cached, batched
-    extraction over one datacube.
-
-    Thread-safe: the pipeline prefetcher calls :meth:`submit_batch` from
-    its worker thread while launchers may probe stats from the main
-    thread.
-    """
-
-    def __init__(self, datacube: Datacube, capacity: int = 1024,
-                 use_kernel: bool = False, tol: float = CANON_TOL,
-                 periods: dict[str, float] | None = None,
-                 verify: bool = False, delta: bool = True,
-                 drift_steps: int = 64):
-        self.datacube = datacube
-        # verify=True machine-checks every cold plan AND every shared
-        # union plan against the invariants in repro.analysis.plan_check
-        # (DESIGN.md §6) — the serving-layer switch for the paper's
-        # byte-exactness contract.
-        self.verify = verify
-        self.extractor = PolytopeExtractor(datacube, use_kernel=use_kernel,
-                                           verify=verify)
-        self.cache = PlanCache(capacity)
-        self.tol = tol
-        # Cyclic-axis periods fold into the cache key: seam-straddling
-        # requests shifted by whole periods hash identically, so the
-        # plan cache hits across the seam (DESIGN.md §2.5).
-        self.periods = dict(periods) if periods is not None \
-            else datacube.axis_periods()
-        # delta=True routes exact-cache misses through the neighborhood
-        # index + delta planner (DESIGN.md §8) before falling back to a
-        # cold Algorithm-1 run; ineligible drifts fall through
-        # transparently, same opt-out contract as the device planner.
-        self.delta_planner = None
-        self.neighborhood = None
-        if delta:
-            self.delta_planner = DeltaPlanner(
-                datacube, slicer=self.extractor.slicer,
-                max_steps=drift_steps)
-            self.neighborhood = NeighborhoodIndex(capacity)
-        self._lock = threading.Lock()
-
-    @property
-    def stats(self) -> CacheStats:
-        with self._lock:
-            return self.cache.stats
-
-    # -- single request ----------------------------------------------------
-    def plan(self, request: Request) -> tuple[ExtractionPlan, bool, str]:
-        """Plan one request through the cache.
-
-        Returns ``(plan, cached, key)``; a hit returns the exact plan
-        object built on the cold miss.
-        """
-        key = request.canonical_hash(self.tol, self.periods)
-        with self._lock:
-            plan = self.cache.get(key)
-            if plan is not None:
-                return plan, True, key
-            plan, _ = self._plan_miss(request, key)
-            return plan, False, key
-
-    def _plan_miss(self, request: Request,
-                   key: str) -> tuple[ExtractionPlan, SliceStats]:
-        """Serve an exact-cache miss (caller holds ``self._lock``):
-        try a delta splice from a drifted neighbor first, cold-plan
-        otherwise; either way install the plan and index the request's
-        signature for future drifts."""
-        if self.delta_planner is not None:
-            out = self._try_delta(request, key)
-            if out is not None:
-                return out
-        with Stage("polytope.planner.cold") as st:
-            plan, stats = self.extractor.plan(request)
-        self.cache.stats.plan_time_s += st.seconds  # unlocked-ok: caller holds _lock
-        self.cache.put(key, plan)           # unlocked-ok: caller holds _lock
-        if self.neighborhood is not None and stats is not None:
-            sig, anchor = request.shape_signature(self.tol)
-            self.neighborhood.add(sig, key, anchor, request, stats)
-        return plan, stats
-
-    def _try_delta(self, request: Request, key: str
-                   ) -> "tuple[ExtractionPlan, SliceStats] | None":
-        """Resolve the request's signature in the neighborhood index and
-        splice from the nearest parent whose drift is eligible.  Spliced
-        plans verify (when ``self.verify``), install under the exact
-        key, and re-index — so a drift *chain* keeps splicing from its
-        latest member instead of walking back to the origin."""
-        with Stage("polytope.planner.delta") as st:
-            sig, anchor = request.shape_signature(self.tol)
-            for entry in self.neighborhood.candidates(sig):
-                shifts = self.delta_planner.axis_shifts(entry.anchor, anchor)
-                if shifts is None:
-                    continue
-                parent = self.cache.peek(entry.key)  # unlocked-ok: caller holds _lock
-                if parent is None:
-                    continue   # parent evicted under the index entry
-                out = self.delta_planner.splice(request, entry.request,
-                                                parent, entry.stats, shifts)
-                if out is None:
-                    continue
-                plan, stats = out
-                if self.verify:
-                    from repro.analysis.plan_check import verify_plan
-
-                    verify_plan(plan, datacube=self.datacube, stats=stats)
-                self.cache.put(key, plan)  # unlocked-ok: caller holds _lock
-                self.neighborhood.add(sig, key, anchor, request, stats)
-                dt = time.perf_counter() - st.start
-                self.cache.stats.delta_hits += 1  # unlocked-ok: caller holds _lock
-                self.cache.stats.delta_time_s += dt  # unlocked-ok: caller holds _lock
-                return plan, stats
-            self.cache.stats.delta_misses += 1  # unlocked-ok: caller holds _lock
-            return None
-
-    def extract(self, request: Request,
-                flat_data: Any | None = None) -> ServiceResult:
-        return self.submit_batch([request], flat_data)[0]
-
-    # -- batched serving -----------------------------------------------------
-    def submit_batch(self, requests: Sequence[Request],
-                     flat_data: Any | None = None) -> list[ServiceResult]:
-        """Serve a batch of concurrent requests.
-
-        Requests are deduped by canonical hash (one plan per distinct
-        geometry), missed plans run Algorithm 1 once, and — when
-        ``flat_data`` is given — all distinct plans are gathered through
-        a single coalesced union read shared across the batch.
-        """
-        results: list[ServiceResult] = []
-        batch_plans: dict[str, ExtractionPlan] = {}
-        delta = CacheStats()
-
-        with Stage("polytope.plan_cache.lookup", delta, "lookup_time_s"):
-            keys = [r.canonical_hash(self.tol, self.periods)
-                    for r in requests]
-            with self._lock:
-                for req, key in zip(requests, keys):
-                    if key in batch_plans:
-                        # same geometry earlier in this batch — share it
-                        self.cache.stats.batch_dedup += 1
-                        results.append(ServiceResult(
-                            request=req, key=key, plan=batch_plans[key],
-                            cached=True))
-                        continue
-                    plan = self.cache.get(key)
-                    stats = None
-                    cached = plan is not None
-                    if plan is None:
-                        plan, stats = self._plan_miss(req, key)
-                    batch_plans[key] = plan
-                    results.append(ServiceResult(
-                        request=req, key=key, plan=plan, cached=cached,
-                        stats=stats))
-
-        # Gather outside the lock: plans are immutable and the results
-        # are local, so concurrent callers only contend on the (short)
-        # planning section, not on the batch I/O.  This discipline is no
-        # longer just prose: repro.analysis.concurrency statically
-        # verifies that all _lock-protected state (the cache) is only
-        # touched inside `with self._lock` blocks — the batch's timings
-        # and byte counts fold into the stats under the lock below.
-        parts = [delta]
-        if flat_data is not None:
-            parts.append(shared_union_gather(
-                self.datacube, results, batch_plans, flat_data,
-                use_kernel=self.extractor.use_kernel, verify=self.verify))
-        with self._lock:
-            merge_stats(parts, into=self.cache.stats)
-        return results
-
-
 # Launch lengths of a served union read: a power of two, at least this.
 # The floor keeps the small point and profile unions on one program.
 UNION_BUCKET_FLOOR = 1024
@@ -587,7 +417,6 @@ def shared_union_gather(datacube: Datacube,
                         results: list[ServiceResult],
                         batch_plans: dict[str, ExtractionPlan],
                         flat_data: Any,
-                        use_kernel: bool = False,
                         verify: bool = False) -> CacheStats:
     """Execute one coalesced union read for ``batch_plans`` and slice each
     result's values out of the shared buffer.
@@ -598,9 +427,8 @@ def shared_union_gather(datacube: Datacube,
     ``gather_time_s`` and its four stages (union build, gather launch,
     copy back, slicing), which share boundary timestamps and so sum to
     it, and the union's runs.  The caller folds the delta into its own
-    stats under its own lock.  Shared between :class:`ExtractionService`
-    and the sharded service in :mod:`repro.serve.sharded` — both funnel
-    a window's distinct plans through exactly one gather.
+    stats under its own lock.  The service in :mod:`repro.serve.sharded`
+    funnels each window's distinct plans through exactly one such read.
     """
     delta = CacheStats()
     nonempty = {k: p for k, p in batch_plans.items() if p.n_points}
@@ -615,16 +443,15 @@ def shared_union_gather(datacube: Datacube,
             from repro.analysis.plan_check import verify_plan
 
             verify_plan(union_plan, datacube=datacube)
-    # A device payload's plain read launches at a bucket's length and is
-    # copied back whole (a device-side trim would compile per length);
-    # a host payload has no compile and the Pallas burst read has its
-    # own grid, so both read the union exactly.
+    # A device payload's read launches at a bucket's length and is copied
+    # back whole (a device-side trim would compile per length); a host
+    # payload has no compile, so it reads the union exactly.
     with Stage("polytope.gather.launch", delta, "launch_time_s",
                start=union_st.end) as st:
         read_plan, lead = union_plan, 0
-        if not use_kernel and isinstance(flat_data, jax.Array):
+        if isinstance(flat_data, jax.Array):
             read_plan, lead = padded_read_plan(union_plan)
-        out = gather(flat_data, read_plan, use_kernel=use_kernel)
+        out = gather(flat_data, read_plan)
     delta.gather_padded_elems = read_plan.n_points - union_plan.n_points
     # One device→host copy of the read; every answer is sliced from it.
     with Stage("polytope.gather.copy", delta, "copy_time_s",

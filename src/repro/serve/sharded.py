@@ -1,8 +1,7 @@
-"""Sharded extraction service with async admission (DESIGN.md §7).
+"""The extraction service, with async admission in front (DESIGN.md §4).
 
-Scaling the plan cache past one lock means exploiting what PR 2 set up:
-cache keys are *stable sha256 content hashes* and plans are *immutable*.
-Three layers build on that:
+Cache keys are *stable sha256 content hashes* and plans are
+*immutable*.  Three layers build on that:
 
 * :class:`ShardedPlanCache` — N independent :class:`PlanCache` shards
   behind a consistent-hash ring (:class:`repro.distributed.sharding.
@@ -10,11 +9,13 @@ Three layers build on that:
   concurrent requests for different geometries contend on different
   locks; adding a shard remaps only ~1/N of the key space and migrates
   exactly those entries.
-* :class:`ShardedExtractionService` — per-shard planning locks replace
-  the single ``ExtractionService`` lock: a cold miss serializes only
-  against cold misses *on the same shard*.  Gathers still run lock-free
-  (plans are immutable) through the same shared union read as the
-  single-lock service.  Replicas connected via :meth:`connect_peer`
+* :class:`ShardedExtractionService` — the service: requests are
+  deduped by canonical hash, cache misses are spliced from a drifted
+  neighbour or planned cold, and a batch's distinct plans are read
+  through one shared union read.  Planning locks are per shard, so a
+  cold miss serializes only against cold misses *on the same shard*;
+  ``shards=1`` is the trivial case.  Gathers run lock-free (plans are
+  immutable).  Replicas connected via :meth:`connect_peer`
   receive every cold plan over the pickled-plan wire format that
   ``repro.analysis.plan_check``'s CLI consumes, so one replica's
   planning work warms the whole fleet — verified on receipt.
@@ -94,7 +95,7 @@ class ShardedPlanCache:
     """
 
     def __init__(self, shards: Iterable[str] | int = 4,
-                 capacity_per_shard: int = 1024, replicas: int = 64):
+                 capacity_per_shard: int = 1024):
         if isinstance(shards, int):
             shards = tuple(f"shard{i}" for i in range(shards))
         names = tuple(shards)
@@ -110,7 +111,7 @@ class ShardedPlanCache:
         # :meth:`peek`).
         self._hoods: dict[str, NeighborhoodIndex] = {
             n: NeighborhoodIndex(capacity_per_shard) for n in names}
-        self.ring = HashRing(names, replicas=replicas)
+        self.ring = HashRing(names)
         self._admin_lock = threading.Lock()
 
     # -- routing -----------------------------------------------------------
@@ -230,42 +231,42 @@ class ShardedPlanCache:
 # ---------------------------------------------------------------------------
 
 class ShardedExtractionService:
-    """``ExtractionService`` semantics with per-shard locking and
+    """Many concurrent polytope requests → deduped, cached, batched
+    extraction over one datacube, with per-shard locking and
     cross-replica plan shipping.
 
-    The single service lock is gone: plan lookups synchronize on the
-    owning shard's cache lock, cold planning serializes on a per-shard
-    planning lock (so concurrent misses of the *same* geometry plan
-    once, while misses on different shards plan in parallel), and
-    gather accounting takes a dedicated I/O lock.  Gathers themselves
-    run lock-free — plans are immutable.
+    Plan lookups synchronize on the owning shard's cache lock, cold
+    planning serializes on a per-shard planning lock (so concurrent
+    misses of the *same* geometry plan once, while misses on different
+    shards plan in parallel), and gather accounting takes a dedicated
+    I/O lock.  Gathers themselves run lock-free — plans are immutable.
+
+    ``verify=True`` machine-checks every cold, spliced and shared union
+    plan against the invariants in ``repro.analysis.plan_check``
+    (DESIGN.md §5).  ``delta=False`` plans every exact-cache miss cold:
+    the reference the delta planner is held to.
     """
 
     def __init__(self, datacube: Datacube, shards: Iterable[str] | int = 4,
-                 capacity_per_shard: int = 1024, use_kernel: bool = False,
-                 tol: float = CANON_TOL,
-                 periods: dict[str, float] | None = None,
-                 verify: bool = False, replicas: int = 64,
-                 name: str = "replica0", delta: bool = True,
-                 drift_steps: int = 64):
+                 capacity_per_shard: int = 1024, verify: bool = False,
+                 name: str = "replica0", delta: bool = True):
         self.datacube = datacube
         self.verify = verify
         self.name = name
-        self.extractor = PolytopeExtractor(datacube, use_kernel=use_kernel,
-                                           verify=verify)
-        self.shards = ShardedPlanCache(shards, capacity_per_shard,
-                                       replicas=replicas)
-        # Same transparent-fallback contract as ExtractionService: an
-        # exact-cache miss first tries a delta splice from the
-        # signature-routed neighborhood before planning cold.
+        self.extractor = PolytopeExtractor(datacube, verify=verify)
+        self.shards = ShardedPlanCache(shards, capacity_per_shard)
+        # An exact-cache miss first tries a delta splice from the
+        # signature-routed neighborhood before planning cold (DESIGN.md
+        # §6); ineligible drifts fall through to the cold plan.
         self.delta_planner = None
         if delta:
-            self.delta_planner = DeltaPlanner(
-                datacube, slicer=self.extractor.slicer,
-                max_steps=drift_steps)
-        self.tol = tol
-        self.periods = dict(periods) if periods is not None \
-            else datacube.axis_periods()
+            self.delta_planner = DeltaPlanner(datacube,
+                                              slicer=self.extractor.slicer)
+        self.tol = CANON_TOL
+        # Cyclic-axis periods fold into the cache key: seam-straddling
+        # requests shifted by whole periods hash identically, so the
+        # plan cache hits across the seam (DESIGN.md §2.5).
+        self.periods = datacube.axis_periods()
         self._plan_locks: dict[str, threading.Lock] = {
             n: threading.Lock() for n in self.shards.shard_names}
         self._peers: list[ShardedExtractionService] = []
@@ -362,9 +363,13 @@ class ShardedExtractionService:
 
     def submit_batch(self, requests: Sequence[Request],
                      flat_data: Any | None = None) -> list[ServiceResult]:
-        """Batch semantics identical to ``ExtractionService.submit_batch``
-        — dedupe by canonical hash, plan misses once, one shared union
-        read — but with no global lock on the planning path."""
+        """Serve a batch of concurrent requests.
+
+        Requests are deduped by canonical hash (one plan per distinct
+        geometry), missed plans are spliced or planned once, and — when
+        ``flat_data`` is given — all distinct plans are gathered through
+        a single coalesced union read shared across the batch.  No
+        global lock is held on the planning path."""
         results: list[ServiceResult] = []
         batch_plans: dict[str, ExtractionPlan] = {}
         delta = CacheStats()
@@ -386,7 +391,7 @@ class ShardedExtractionService:
         if flat_data is not None:
             parts.append(shared_union_gather(
                 self.datacube, results, batch_plans, flat_data,
-                use_kernel=self.extractor.use_kernel, verify=self.verify))
+                verify=self.verify))
         with self._io_lock:
             merge_stats(parts, into=self.io_stats)
         return results

@@ -1,4 +1,4 @@
-"""Static verification layer (repro.analysis, DESIGN.md §6).
+"""Static verification layer (repro.analysis, DESIGN.md §5).
 
 Three analyzer families, each tested two ways:
 
@@ -15,15 +15,16 @@ mutation must be caught.
 
 from __future__ import annotations
 
-import json
+import pickle
 from dataclasses import replace
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from repro.analysis import (check_bench_file, check_lock_source, check_plan,
-                            lint_source, lint_tree, verify_plan)
+from repro.analysis import (check_lock_source, check_plan, lint_source,
+                            lint_tree, verify_plan)
+from repro.analysis.__main__ import main as analysis_main
 from repro.analysis.concurrency import check_lock_discipline
 from repro.analysis.plan_check import PlanVerificationError
 from repro.core import (Box, OrderedAxis, Polygon, PolytopeExtractor,
@@ -506,69 +507,62 @@ class TestRepoTreeClean:
         assert [str(d) for d in check_lock_discipline(SRC)] == []
 
     def test_service_lock_state_is_inferred(self):
-        # The checker must actually see ExtractionService's protected
+        # The checker must actually see the admission queue's protected
         # state — guard against the rule silently matching nothing.
         import ast
 
         from repro.analysis.concurrency import _ProtectedCollector
 
-        src = (SRC / "serve" / "extraction.py").read_text()
+        src = (SRC / "serve" / "sharded.py").read_text()
         for node in ast.walk(ast.parse(src)):
             if isinstance(node, ast.ClassDef) \
-                    and node.name == "ExtractionService":
+                    and node.name == "AdmissionQueue":
                 c = _ProtectedCollector()
                 for stmt in node.body:
                     c.visit(stmt)
                 assert "_lock" in c.locks
-                assert "cache" in c.protected
+                assert {"_pending", "_stamps", "_closed",
+                        "stats"} <= c.protected
                 return
-        pytest.fail("ExtractionService not found")
+        pytest.fail("AdmissionQueue not found")
 
 
 # ---------------------------------------------------------------------------
-# bench schema
+# the command line (python -m repro.analysis)
 # ---------------------------------------------------------------------------
-class TestBenchSchema:
-    def test_repo_bench_file_is_clean(self):
-        assert [str(d) for d in
-                check_bench_file(REPO / "BENCH_extraction.json")] == []
+class TestCli:
+    def test_all_is_clean_on_the_tree(self, capsys):
+        # the CI gate: lint + locks + planner self-check
+        assert analysis_main(["--all"]) == 0
+        assert "all checks clean" in capsys.readouterr().out
 
-    def test_missing_key_is_caught(self, tmp_path):
-        p = tmp_path / "b.json"
-        p.write_text(json.dumps({"bench": "extraction", "rows": [
-            {"example": "x", "polytope_bytes": 1}]}))
-        diags = check_bench_file(p)
-        assert diags and all(d.rule == "bench-schema" for d in diags)
+    def test_no_check_asked_is_a_usage_error(self, capsys):
+        assert analysis_main([]) == 2
+        assert "usage" in capsys.readouterr().out
 
-    def test_invalid_json_is_caught(self, tmp_path):
-        p = tmp_path / "b.json"
-        p.write_text("{not json")
-        assert [d.rule for d in check_bench_file(p)] == ["bench-schema"]
+    @pytest.mark.parametrize("form,rc", [("envelope", 0), ("bare", 0),
+                                         ("truncated", 1)])
+    def test_plan_file(self, tmp_path, capsys, form, rc):
+        from repro.serve.sharded import serialize_plan
 
-    def test_empty_rows_is_caught(self, tmp_path):
-        p = tmp_path / "b.json"
-        p.write_text(json.dumps({"bench": "extraction", "rows": []}))
-        assert [d.rule for d in check_bench_file(p)] == ["bench-schema"]
+        cube, plan, _ = small_plan()
+        blob = serialize_plan("key", plan, n_elements=cube.n_elements)
+        if form == "bare":
+            blob = pickle.dumps(plan)
+        elif form == "truncated":
+            blob = blob[:len(blob) // 2]
+        path = tmp_path / "plan.pkl"
+        path.write_bytes(blob)
+        assert analysis_main(["--plan", str(path)]) == rc
+        if rc:
+            assert "plan-file" in capsys.readouterr().err
 
-    def test_repo_serve_bench_file_is_clean(self):
-        assert [str(d) for d in
-                check_bench_file(REPO / "BENCH_serve.json")] == []
-
-    def test_serve_row_missing_key_is_caught(self, tmp_path):
-        p = tmp_path / "b.json"
-        p.write_text(json.dumps({"bench": "serve", "rows": [
-            {"scenario": "zipf", "p50_ms": 1.0}]}))
-        diags = check_bench_file(p)
-        assert diags and all(d.rule == "bench-schema" for d in diags)
-        assert any("p99_ms" in d.message for d in diags)
-
-    def test_unknown_bench_tag_is_caught(self, tmp_path):
-        p = tmp_path / "b.json"
-        p.write_text(json.dumps({"bench": "warp-drive", "rows": [
-            {"scenario": "x"}]}))
-        diags = check_bench_file(p)
-        assert [d.rule for d in diags] == ["bench-schema"]
-        assert "serve" in diags[0].message  # lists registered tags
+    def test_lint_diagnostic_fails_the_command(self, tmp_path, capsys):
+        bad = tmp_path / "core" / "slicer.py"
+        bad.parent.mkdir()
+        bad.write_text("def f(x):\n    return x.astype('float32')\n")
+        assert analysis_main(["--lint", "--root", str(tmp_path)]) == 1
+        assert "1 diagnostic(s)" in capsys.readouterr().err
 
 
 # ---------------------------------------------------------------------------
@@ -628,11 +622,11 @@ class TestCheckedCast:
 class TestServiceVerify:
     def test_irregular_weather_round_trip_verified(self):
         from repro.dataplane.weather import IrregularWeatherCube
-        from repro.serve.extraction import ExtractionService
+        from repro.serve.sharded import ShardedExtractionService
 
         iwc = IrregularWeatherCube(n_lat=48, n_lon=96)
         data = iwc.field_data(seed=3)
-        svc = ExtractionService(iwc.cube, verify=True)
+        svc = ShardedExtractionService(iwc.cube, shards=1, verify=True)
         for req in (iwc.country_request("uk"),
                     iwc.seam_box_request(40.0, 60.0, -20.0, 20.0),
                     iwc.timeseries_request(51.5, 0.0, 43200.0,

@@ -19,6 +19,29 @@ class TestTokenCube:
         b2 = tc.batch(3, 4, 32)
         np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
 
+    def test_replayed_batch_is_served_from_the_plan_cache(self):
+        """A step replayed (fault-tolerant restore) gets the same tokens,
+        each row the document's own window, with every row's plan a
+        cache hit: nothing is planned again."""
+        tc = TokenCube(n_docs=8, doc_len=256)
+        step, rows, seq_len = 5, 6, 32
+        first = tc.batch(step, rows, seq_len)
+        before = tc.service.stats
+        replay = tc.batch(step, rows, seq_len)
+        after = tc.service.stats
+        np.testing.assert_array_equal(replay["tokens"], first["tokens"])
+        np.testing.assert_array_equal(replay["labels"], first["labels"])
+        assert after.misses == before.misses
+        assert (after.hits - before.hits
+                + after.batch_dedup - before.batch_dedup) == rows
+        docs = tc.materialize().reshape(tc.n_docs, tc.doc_len)
+        windows = np.lib.stride_tricks.sliding_window_view(
+            docs, seq_len + 1, axis=1)
+        for tok, lab in zip(first["tokens"], first["labels"]):
+            row = np.append(tok, lab[-1])
+            assert (windows == row).all(axis=-1).any(), \
+                "a row is not a window of one document"
+
     def test_labels_are_shifted_tokens(self):
         tc = TokenCube(n_docs=4, doc_len=128)
         b = tc.batch(0, 2, 16)

@@ -1,4 +1,4 @@
-"""Delta planner (DESIGN.md §8): differential suite against cold planning.
+"""Delta planner (DESIGN.md §6): differential suite against cold planning.
 
 The contract under test: for every eligible drift, ``DeltaPlanner.splice``
 must emit a plan *byte-identical* to running Algorithm 1 cold on the
@@ -19,7 +19,7 @@ from repro.analysis.plan_check import verify_plan
 from repro.core import (Box, DeltaPlanner, Polygon, PolytopeExtractor,
                         Request, Select, Span)
 from repro.dataplane.weather import COUNTRIES, IrregularWeatherCube
-from repro.serve.extraction import ExtractionService, NeighborhoodIndex
+from repro.serve.extraction import NeighborhoodIndex
 from repro.serve.sharded import ShardedExtractionService
 
 LON_STEP = 10.0          # 360 / 36
@@ -272,7 +272,8 @@ class TestFallbackTransparency:
         assert planner.splice(r_new, r_old, p, s, shifts) is None
 
     def test_service_falls_back_cold_on_lat_drift(self, wcube):
-        svc = ExtractionService(wcube.cube, verify=True)
+        svc = ShardedExtractionService(wcube.cube, shards=1,
+                                       verify=True)
         cold = PolytopeExtractor(wcube.cube)
         r0 = lon_box(34.0, 76.0, lat_lo=20.0, lat_hi=60.0)
         r1 = lon_box(34.0, 76.0, lat_lo=25.0, lat_hi=65.0)
@@ -285,7 +286,8 @@ class TestFallbackTransparency:
 
 class TestServiceDelta:
     def test_drift_stream_counters_and_values(self, wcube):
-        svc = ExtractionService(wcube.cube, verify=True)
+        svc = ShardedExtractionService(wcube.cube, shards=1,
+                                       verify=True)
         data = wcube.field_data(seed=3)
         results = []
         for k in range(6):
@@ -304,8 +306,10 @@ class TestServiceDelta:
         assert res.cached
 
     def test_spliced_equals_cold_service(self, wcube):
-        warm = ExtractionService(wcube.cube, verify=True, delta=True)
-        cold = ExtractionService(wcube.cube, verify=True, delta=False)
+        warm = ShardedExtractionService(wcube.cube, shards=1, verify=True,
+                                        delta=True)
+        cold = ShardedExtractionService(wcube.cube, shards=1, verify=True,
+                                        delta=False)
         for k in range(4):
             r = lon_box(34.0 + k * LON_STEP, 76.0 + k * LON_STEP)
             pw, _, _ = warm.plan(r)
@@ -316,7 +320,8 @@ class TestServiceDelta:
         assert cold.stats.delta_hits == 0
 
     def test_evicted_parent_plans_cold(self, wcube):
-        svc = ExtractionService(wcube.cube, capacity=1, verify=True)
+        svc = ShardedExtractionService(wcube.cube, shards=1,
+                                       capacity_per_shard=1, verify=True)
         r0, r1 = lon_box(34.0, 76.0), lon_box(44.0, 86.0)
         svc.plan(r0)
         # parent evicted by an unrelated plan: neighborhood entry is
@@ -326,7 +331,7 @@ class TestServiceDelta:
         assert not cached and plan.n_points > 0
 
     def test_delta_disabled_has_no_neighborhood(self, wcube):
-        svc = ExtractionService(wcube.cube, delta=False)
+        svc = ShardedExtractionService(wcube.cube, shards=1, delta=False)
         svc.plan(lon_box(34.0, 76.0))
         svc.plan(lon_box(44.0, 86.0))
         assert svc.stats.delta_hits == 0 and svc.stats.delta_misses == 0

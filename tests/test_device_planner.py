@@ -186,11 +186,25 @@ class TestBurstGather:
 
         data = iwc.field_data().astype(np.float32)   # device-native dtype
         req = iwc.seam_box_request(35.0, 62.0, -25.0, 25.0)
-        pe = PolytopeExtractor(iwc.cube, device_planner=True,
-                               burst_gather=True)
-        res = pe.extract(req, jnp.asarray(data))
+        plan, _ = PolytopeExtractor(iwc.cube, device_planner=True).plan(req)
+        values = gather(jnp.asarray(data), plan, burst=True)
         host = PolytopeExtractor(iwc.cube).extract(req, data)
-        np.testing.assert_array_equal(np.asarray(res.values), host.values)
+        np.testing.assert_array_equal(np.asarray(values), host.values)
+
+    def test_extractor_reads_a_device_payload(self, iwc):
+        """With no path flags the extractor reads a device payload with
+        ``jnp.take``: the values a host payload gives, as a device
+        array."""
+        import jax
+        import jax.numpy as jnp
+
+        data = iwc.field_data().astype(np.float32)
+        req = iwc.country_request("uk")
+        pe = PolytopeExtractor(iwc.cube)
+        dev = pe.extract(req, jnp.asarray(data))
+        assert isinstance(dev.values, jax.Array)
+        np.testing.assert_array_equal(np.asarray(dev.values),
+                                      pe.extract(req, data).values)
 
 
 # ---------------------------------------------------------------------------

@@ -235,7 +235,7 @@ class TestDryRunEntry:
 
 
 # ---------------------------------------------------------------------------
-# Consistent-hash routing (plan-cache sharding, DESIGN.md §7)
+# Consistent-hash routing (plan-cache sharding, DESIGN.md §4)
 # ---------------------------------------------------------------------------
 
 def _keys(n, seed):

@@ -12,7 +12,8 @@ import pytest
 from repro.core import (Box, ConvexPolytope, Disk, OrderedAxis, Request,
                         Select, Slicer, Span, TensorDatacube, Union)
 from repro.dataplane.pipeline import CachedExtractionSource, Prefetcher
-from repro.serve.extraction import ExtractionService, PlanCache
+from repro.serve.extraction import PlanCache
+from repro.serve.sharded import ShardedExtractionService
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -107,7 +108,7 @@ class TestPlanCacheLRU:
 
 class TestExtractionService:
     def test_repeat_request_served_from_cache(self):
-        svc = ExtractionService(small_cube())
+        svc = ShardedExtractionService(small_cube(), shards=1)
         cold = svc.extract(tri_request())
         assert not cold.cached
         assert cold.stats is not None            # cold plan ran Alg. 1
@@ -121,7 +122,7 @@ class TestExtractionService:
 
     def test_hit_offsets_match_independent_cold_plan(self):
         cube = small_cube()
-        svc = ExtractionService(cube)
+        svc = ShardedExtractionService(cube, shards=1)
         svc.extract(tri_request())
         hit = svc.extract(tri_request())
         ref, _ = Slicer(cube).extract_plan(tri_request())
@@ -130,7 +131,7 @@ class TestExtractionService:
     def test_batch_dedupes_and_shares_reads(self):
         cube = small_cube()
         data = np.arange(cube.n_elements, dtype=np.float64)
-        svc = ExtractionService(cube)
+        svc = ShardedExtractionService(cube, shards=1)
         reqs = [tri_request(), tri_request(), tri_request(1.0)]
         results = svc.submit_batch(reqs, data)
         assert svc.stats.misses == 2             # two distinct geometries
@@ -144,7 +145,7 @@ class TestExtractionService:
         assert svc.stats.sharing_factor > 1.0
 
     def test_equivalent_permuted_batch_members_hit(self):
-        svc = ExtractionService(small_cube())
+        svc = ShardedExtractionService(small_cube(), shards=1)
         s1 = Box(("a", "b"), [0, 0], [3, 3])
         s2 = Disk(("a", "b"), (6.0, 6.0), 2.0)
         svc.extract(Request([Union([s1, s2])]))
@@ -152,7 +153,8 @@ class TestExtractionService:
         assert res.cached
 
     def test_lru_eviction_end_to_end(self):
-        svc = ExtractionService(small_cube(), capacity=2)
+        svc = ShardedExtractionService(small_cube(), shards=1,
+                                       capacity_per_shard=2)
         r1, r2, r3 = tri_request(0.0), tri_request(1.0), tri_request(2.0)
         svc.extract(r1)
         svc.extract(r2)
@@ -166,7 +168,7 @@ class TestExtractionService:
     def test_empty_plan_values(self):
         cube = small_cube()
         data = np.arange(cube.n_elements, dtype=np.float64)
-        svc = ExtractionService(cube)
+        svc = ShardedExtractionService(cube, shards=1)
         # box entirely outside the grid → empty plan
         res = svc.extract(Request([Box(("a", "b"), [50, 50], [60, 60])]),
                           data)
@@ -178,7 +180,7 @@ class TestPrefetcherReusesPlans:
     def test_plans_cached_across_steps(self):
         cube = small_cube()
         data = np.arange(cube.n_elements, dtype=np.float64)
-        svc = ExtractionService(cube)
+        svc = ShardedExtractionService(cube, shards=1)
         # recurring production mix: step alternates between two crops
         crops = [tri_request(0.0), tri_request(2.0)]
         src = CachedExtractionSource(svc, lambda s: crops[s % 2], data)
@@ -372,7 +374,8 @@ class TestQuantizeStraddle:
         assert r0.shape_signature()[0] == r1.shape_signature()[0]
 
     def test_neighborhood_recovers_straddled_miss(self):
-        svc = ExtractionService(small_cube(), verify=True)
+        svc = ShardedExtractionService(small_cube(), shards=1,
+                                       verify=True)
         r0, r1 = self.box_req(), self.box_req(self.JITTER)
         p0, cached0, _ = svc.plan(r0)
         p1, cached1, _ = svc.plan(r1)
@@ -384,7 +387,8 @@ class TestQuantizeStraddle:
     def test_off_by_one_quantum_anchor_tolerance(self):
         # a whole-step drift plus sub-quantum jitter still resolves to
         # an integral step count (the ratio check absorbs the jitter)
-        svc = ExtractionService(small_cube(), verify=True)
+        svc = ShardedExtractionService(small_cube(), shards=1,
+                                       verify=True)
         svc.plan(self.box_req())
         plan, cached, _ = svc.plan(self.box_req(1.0 + self.JITTER))
         assert not cached

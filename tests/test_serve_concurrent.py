@@ -1,5 +1,5 @@
 """Concurrency stress suite for the sharded extraction service
-(DESIGN.md §7).
+(DESIGN.md §4).
 
 Barrier-started thread swarms hammer ``submit_batch`` and the
 ``AdmissionQueue`` with duplicate, seam-shifted, and disjoint requests;
@@ -27,7 +27,7 @@ import pytest
 from repro.core import PolytopeExtractor, gather
 from repro.dataplane.weather import (IrregularWeatherCube, WeatherCube,
                                      request_population)
-from repro.serve.extraction import ExtractionService, PlanCache
+from repro.serve.extraction import PlanCache
 from repro.serve.sharded import (AdmissionQueue, ShardedExtractionService,
                                  ShardedPlanCache, deserialize_plan,
                                  serialize_plan)
@@ -98,9 +98,12 @@ class TestSubmitBatchSwarm:
         picks = [population[(tid + j) % k] for j in range(6)]
         return picks + picks[:2]   # in-batch duplicates
 
-    def test_sharded_byte_identity_and_stats(self, weather):
+    @pytest.mark.parametrize("shards", [1, 4])
+    def test_sharded_byte_identity_and_stats(self, weather, shards):
+        """One shard is the trivial case of the same service: one cache
+        and one planning lock, raced by every thread."""
         wc, data, population = weather
-        svc = ShardedExtractionService(wc.cube, shards=4)
+        svc = ShardedExtractionService(wc.cube, shards=shards)
         refs = {id(r): v for r, v in
                 zip(population, reference_values(wc.cube, data, population))}
 
@@ -127,30 +130,13 @@ class TestSubmitBatchSwarm:
         # 2 in-batch duplicates per batch, every batch
         assert s.batch_dedup == 2 * N_THREADS * N_ITERS
 
-    def test_single_lock_service_parity(self, weather):
-        """The original single-lock service stays race-free too."""
-        wc, data, population = weather
-        svc = ExtractionService(wc.cube)
-        refs = reference_values(wc.cube, data, population)
-
-        def worker(tid):
-            for _ in range(N_ITERS):
-                idx = [(tid + j) % len(population) for j in range(4)]
-                results = svc.submit_batch([population[i] for i in idx],
-                                           data)
-                for i, res in zip(idx, results):
-                    assert np.array_equal(res.values, refs[i])
-
-        run_swarm(N_THREADS, worker)
-        s = svc.stats
-        assert s.lookups == s.hits + s.misses
-
-    def test_seam_shifted_requests_share_one_plan(self, irregular):
+    @pytest.mark.parametrize("shards", [1, 4])
+    def test_seam_shifted_requests_share_one_plan(self, irregular, shards):
         """Period-shifted seam crops hash identically, so a swarm half
         on lon −15…15 and half on lon 345…375 contends on ONE cache
         entry — and both halves read byte-identical values."""
         icw, data = irregular
-        svc = ShardedExtractionService(icw.cube, shards=4)
+        svc = ShardedExtractionService(icw.cube, shards=shards)
         base = icw.seam_box_request(40.0, 60.0, -15.0, 15.0)
         shifted = icw.seam_box_request(40.0, 60.0, 345.0, 375.0)
         assert (base.canonical_hash(svc.tol, svc.periods)
@@ -337,6 +323,18 @@ class TestShardRebalance:
         cache.add_shard("doomed")
         cache.remove_shard("doomed")
         assert cache.stats.hits == hits_before
+
+    def test_last_shard_cannot_be_removed(self):
+        """One shard is the least a cache holds: draining it would leave
+        the ring with nowhere to route, so it is refused and the entry
+        stays where it was."""
+        cache = ShardedPlanCache(1)
+        key = _synthetic_keys(1, self.SEED)[0]
+        cache.put(key, "p")
+        with pytest.raises(ValueError, match="shard0"):
+            cache.remove_shard("shard0")
+        assert cache.shard_names == ("shard0",)
+        assert cache.get(key) == "p"
 
     def test_add_then_remove_restores_routing(self):
         cache = ShardedPlanCache(shards=4, capacity_per_shard=self.N_KEYS)

@@ -2,8 +2,6 @@
 cube: answers come from the device payload, a failing client fails the
 launcher, and the compile cache goes where the environment says."""
 
-import json
-
 import jax
 import numpy as np
 import pytest
@@ -23,9 +21,8 @@ def no_cache_change(monkeypatch):
     monkeypatch.setattr(serve, "use_compile_cache", lambda: None)
 
 
-def test_answers_equal_payload_at_plan_offsets(tmp_path):
-    out = tmp_path / "serve.json"
-    args = serve.build_parser().parse_args(ARGS + ["--bench-out", str(out)])
+def test_answers_equal_payload_at_plan_offsets(capsys):
+    args = serve.build_parser().parse_args(ARGS)
     wc = weather.WeatherCube(n=32, n_times=2, n_levels=3,
                              dtype=np.dtype(np.float32))
     host, payload = serve.load_payload(wc, args.seed)
@@ -35,22 +32,22 @@ def test_answers_equal_payload_at_plan_offsets(tmp_path):
     assert len(answers) == row["requests"] == 24
     for res in answers:
         np.testing.assert_array_equal(res.values, host[res.plan.offsets])
-    assert json.loads(out.read_text())["rows"] == [row]
+    assert "served 24 requests from 3 threads" in capsys.readouterr().out
 
 
-def test_failing_request_exits_nonzero(monkeypatch, tmp_path,
-                                       no_cache_change, capsys):
+def test_failing_request_exits_nonzero(monkeypatch, no_cache_change,
+                                       capsys):
     bad = Request([Select("time", ["not-a-time"])])
     real = weather.request_population
     # rank 0 is the most frequent Zipf draw, so the bad request is sent
     monkeypatch.setattr(weather, "request_population",
                         lambda wc: [bad] + real(wc))
-    out = tmp_path / "serve.json"
     with pytest.raises(SystemExit) as exc:
-        serve.main(ARGS + ["--bench-out", str(out)])
+        serve.main(ARGS)
     assert exc.value.code == 1
-    assert "client threads failed" in capsys.readouterr().err
-    assert not out.exists()
+    out, err = capsys.readouterr()
+    assert "client threads failed" in err
+    assert "served" not in out          # a failed run reports no result
 
 
 @pytest.mark.parametrize("env", [None, "cache-from-env"])

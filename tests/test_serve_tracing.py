@@ -20,8 +20,7 @@ import pytest
 
 from repro.core import Box, Request, Select
 from repro.dataplane.weather import IrregularWeatherCube
-from repro.serve.extraction import (CacheStats, ExtractionService, Stage,
-                                    merge_stats)
+from repro.serve.extraction import CacheStats, Stage, merge_stats
 from repro.serve.sharded import (AdmissionQueue, ShardedExtractionService,
                                  ShardedPlanCache)
 
@@ -65,7 +64,7 @@ def serve_windows(queue, batches):
 
 
 SERVICES = {"sharded": lambda c: ShardedExtractionService(c, shards=3),
-            "single-lock": lambda c: ExtractionService(c)}
+            "one-shard": lambda c: ShardedExtractionService(c, shards=1)}
 
 
 @pytest.mark.parametrize("kind", sorted(SERVICES))

@@ -21,7 +21,7 @@ from repro.core import (Box, CyclicAxis, CyclicTransform, MappedTransform,
                         TensorDatacube, TransformedDatacube, Union)
 from repro.dataplane.weather import (COUNTRIES, IrregularWeatherCube,
                                      gaussian_latitudes)
-from repro.serve.extraction import ExtractionService
+from repro.serve.sharded import ShardedExtractionService
 
 PERIOD = 360.0
 
@@ -273,7 +273,7 @@ class TestSeamCanonicalization:
 
     def test_plan_cache_hits_across_the_seam(self):
         iwc = small_irregular()
-        svc = ExtractionService(iwc.cube)
+        svc = ShardedExtractionService(iwc.cube, shards=1)
         base = iwc.seam_box_request(30.0, 60.0, -15.0, 15.0)
         shifted = Request([Select("datetime", [0.0]), Select("level", [0.0]),
                            Box(("lat", "lon"), [30.0, 345.0],
@@ -286,7 +286,7 @@ class TestSeamCanonicalization:
 
     def test_service_plans_match_plain_slicer_on_transformed_cube(self):
         iwc = small_irregular()
-        svc = ExtractionService(iwc.cube)
+        svc = ShardedExtractionService(iwc.cube, shards=1)
         req = iwc.country_request("uk")
         res = svc.extract(req)
         ref, _ = Slicer(iwc.cube).extract_plan(iwc.country_request("uk"))
