@@ -18,7 +18,7 @@ possible Polytope requests".
 from __future__ import annotations
 
 import hashlib
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from typing import Any, Sequence
 
 import numpy as np
@@ -458,6 +458,29 @@ class Request:
             cache[(tol, pkey)] = h
         return h
 
+    def spec_digest(self, tol: float = CANON_TOL,
+                    periods: dict[str, float] | None = None) -> str | None:
+        """Fixed-size digest of the raw request: what
+        :meth:`canonical_hash` reads, before any decomposition.
+
+        Equal digests mean equal shapes, field by field, so they mean
+        equal canonical hashes; the service keys repeated requests by
+        it and skips their decomposition.  Arrays are fed as dtype,
+        shape and bytes; other values by their exact ``repr``, as
+        :func:`canonical_key` reads select values.  ``None`` where a
+        shape's own class is not a dataclass, whose inputs cannot be
+        enumerated.
+        Memoized per Request object like :meth:`canonical_hash`.
+        """
+        pkey = tuple(sorted(periods.items())) if periods else ()
+        cache = self.__dict__.setdefault("_spec_digests", {})
+        if (tol, pkey) not in cache:
+            h = hashlib.blake2b(digest_size=16)
+            _feed(h, repr((tol, pkey)))
+            cache[(tol, pkey)] = (h.hexdigest() if _feed(h, self.shapes)
+                                  else None)
+        return cache[(tol, pkey)]
+
     def shape_signature(self, tol: float = CANON_TOL
                         ) -> tuple[str, dict[str, float]]:
         """Translation-invariant signature hash + per-axis anchor.
@@ -473,6 +496,40 @@ class Request:
             out = signature_hash(self.polytopes(), self.selects(), tol)
             cache[tol] = out
         return out
+
+
+def _feed(h: Any, v: Any) -> bool:
+    """Feed ``v`` into the digest ``h`` (:meth:`Request.spec_digest`):
+    a shape as its class and its fields in order, a list or tuple item
+    by item, an array (NumPy's, JAX's) as dtype, shape and bytes, since
+    its ``repr`` abbreviates, anything else as its type and ``repr``.
+    Every token carries its length, so no two values feed the same
+    stream.  False where a shape's own class is not a dataclass: an
+    undecorated subclass may read attributes that are no field."""
+    if isinstance(v, Shape):
+        if "__dataclass_fields__" not in vars(type(v)):
+            return False
+        _token(h, f"{type(v).__module__}.{type(v).__qualname__}")
+        return all(_feed(h, getattr(v, f.name)) for f in fields(v))
+    if hasattr(v, "__array__"):
+        a = np.asarray(v)
+        _token(h, f"{type(v).__name__} {a.dtype.str} {a.shape}")
+        if a.dtype.hasobject:       # the bytes would be pointers
+            return all(_feed(h, x) for x in a.flat)
+        _token(h, np.ascontiguousarray(a).tobytes())
+        return True
+    if isinstance(v, (list, tuple)):
+        _token(h, f"{type(v).__name__} {len(v)}")
+        return all(_feed(h, x) for x in v)
+    _token(h, f"{type(v).__name__} {v!r}")
+    return True
+
+
+def _token(h: Any, data: str | bytes) -> None:
+    if isinstance(data, str):
+        data = data.encode()
+    h.update(len(data).to_bytes(8, "little"))
+    h.update(data)
 
 
 # ---------------------------------------------------------------------------

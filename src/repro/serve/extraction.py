@@ -13,6 +13,8 @@ scale.  This module holds:
   request's canonical content hash (``Request.canonical_hash``), so
   permuted-but-equivalent requests collide, with hit/miss/eviction
   counters in :class:`CacheStats` exposed like ``SliceStats``;
+* :class:`KeyMemo`, which finds a repeated request's key by its raw
+  shapes, before the decomposition the canonical hash starts from;
 * :class:`NeighborhoodIndex`, which finds a drifted request's parent
   plan for the delta planner;
 * :class:`Stage`, one stage of the served path as a profiler span and a
@@ -61,7 +63,7 @@ class CacheStats:
     delta_hits: int = 0             # misses served by plan splicing
     delta_misses: int = 0           # misses with no splicable neighbor
     delta_time_s: float = 0.0       # cumulative splice walltime
-    lookup_time_s: float = 0.0      # batch loop: hash, route, get, plan
+    lookup_time_s: float = 0.0      # batch loop: key, route, get, plan
     # gather_time_s split into its four stages (they sum to it)
     union_time_s: float = 0.0       # host union build
     launch_time_s: float = 0.0      # gather call: trace, compile, dispatch
@@ -69,6 +71,8 @@ class CacheStats:
     slice_time_s: float = 0.0       # per-request slices of the union
     gather_padded_elems: int = 0    # launch length less union length
     union_runs: int = 0             # runs of the windows' merged unions
+    key_memo_hits: int = 0          # keys found by the raw request's digest
+    key_memo_misses: int = 0        # keys computed by canonical_hash
 
     @property
     def lookups(self) -> int:
@@ -230,6 +234,42 @@ class PlanCache:
         """Consistent copy of the counters (safe to aggregate lock-free)."""
         with self._lock:
             return replace(self.stats)
+
+
+class KeyMemo:
+    """Bounded LRU of ``Request.spec_digest → canonical_hash``.
+
+    A repeat of a request's raw shapes gets its plan-cache key without
+    the decomposition (ear-clipping a country polygon) that
+    ``canonical_hash`` starts from.  The key is a pure function of the
+    raw request, so a memoized key is the one the hash would give.
+    Thread-safe behind its own lock.
+    """
+
+    def __init__(self, capacity: int):
+        if capacity < 1:
+            raise ValueError("capacity must be >= 1")
+        self.capacity = capacity
+        self._od: OrderedDict[str, str] = OrderedDict()
+        self._lock = threading.Lock()
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._od)
+
+    def get(self, digest: str) -> str | None:
+        with self._lock:
+            key = self._od.get(digest)
+            if key is not None:
+                self._od.move_to_end(digest)
+            return key
+
+    def put(self, digest: str, key: str) -> None:
+        with self._lock:
+            self._od[digest] = key
+            self._od.move_to_end(digest)
+            while len(self._od) > self.capacity:
+                self._od.popitem(last=False)
 
 
 @dataclass

@@ -10,7 +10,8 @@ Cache keys are *stable sha256 content hashes* and plans are
   locks; adding a shard remaps only ~1/N of the key space and migrates
   exactly those entries.
 * :class:`ShardedExtractionService` — the service: requests are
-  deduped by canonical hash, cache misses are spliced from a drifted
+  deduped by canonical hash (found by the raw request's digest when its
+  shapes were seen before), cache misses are spliced from a drifted
   neighbour or planned cold, and a batch's distinct plans are read
   through one shared union read.  Planning locks are per shard, so a
   cold miss serializes only against cold misses *on the same shard*;
@@ -46,9 +47,10 @@ from repro.core.delta_planner import DeltaPlanner
 from repro.core.index_tree import ExtractionPlan
 from repro.core.shapes import CANON_TOL
 from repro.distributed.sharding import HashRing
-from repro.serve.extraction import (CacheStats, NeighborhoodIndex,
-                                    PlanCache, ServiceResult, Stage,
-                                    merge_stats, shared_union_gather)
+from repro.serve.extraction import (CacheStats, KeyMemo,
+                                    NeighborhoodIndex, PlanCache,
+                                    ServiceResult, Stage, merge_stats,
+                                    shared_union_gather)
 
 
 # ---------------------------------------------------------------------------
@@ -267,6 +269,10 @@ class ShardedExtractionService:
         # requests shifted by whole periods hash identically, so the
         # plan cache hits across the seam (DESIGN.md §2.5).
         self.periods = datacube.axis_periods()
+        # Repeated raw requests find their key without a decomposition;
+        # as many keys as the plan cache holds plans.
+        self._keys = KeyMemo(len(self.shards.shard_names)
+                             * capacity_per_shard)
         self._plan_locks: dict[str, threading.Lock] = {
             n: threading.Lock() for n in self.shards.shard_names}
         self._peers: list[ShardedExtractionService] = []
@@ -283,13 +289,25 @@ class ShardedExtractionService:
 
     # -- planning ----------------------------------------------------------
     def plan(self, request: Request) -> tuple[ExtractionPlan, bool, str]:
-        plan, cached, key, _ = self._plan_one(request)
-        return plan, cached, key
+        res = self.submit_batch([request])[0]
+        return res.plan, res.cached, res.key
 
-    def _plan_one(self, request: Request,
-                  key: str | None = None):
-        if key is None:
-            key = request.canonical_hash(self.tol, self.periods)
+    def _key_of(self, request: Request, delta: CacheStats) -> str:
+        """The request's plan-cache key: from the memo where its raw
+        shapes were seen before, else its canonical hash, then
+        memoized.  Counts the memo's hit or miss in ``delta``."""
+        digest = request.spec_digest(self.tol, self.periods)
+        key = None if digest is None else self._keys.get(digest)
+        if key is not None:
+            delta.key_memo_hits += 1
+            return key
+        delta.key_memo_misses += 1
+        key = request.canonical_hash(self.tol, self.periods)
+        if digest is not None:
+            self._keys.put(digest, key)
+        return key
+
+    def _plan_one(self, request: Request, key: str):
         shard, cache = self.shards.entry_of(key)
         # Uncounted membership probe first, so the counted lookup below
         # runs exactly once per request (a double-check get would score
@@ -375,7 +393,7 @@ class ShardedExtractionService:
         delta = CacheStats()
         with Stage("polytope.plan_cache.lookup", delta, "lookup_time_s"):
             for req in requests:
-                key = req.canonical_hash(self.tol, self.periods)
+                key = self._key_of(req, delta)
                 if key in batch_plans:
                     self.shards.entry_of(key)[1].record(batch_dedup=1)
                     results.append(ServiceResult(

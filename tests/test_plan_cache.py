@@ -6,11 +6,14 @@ bottom of this module: a seeded-rng class that always runs, and a
 hypothesis class that deepens the search when hypothesis is installed
 (skipped cleanly otherwise — the container does not ship it)."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (Box, ConvexPolytope, Disk, OrderedAxis, Request,
-                        Select, Slicer, Span, TensorDatacube, Union)
+from repro.core import (CANON_TOL, Box, ConvexPolytope, Disk, Ellipsoid,
+                        OrderedAxis, Path, Point, Polygon, Request, Select,
+                        Slicer, Span, TensorDatacube, Union)
+from repro.core.shapes import Shape
 from repro.dataplane.pipeline import CachedExtractionSource, Prefetcher
 from repro.serve.extraction import PlanCache
 from repro.serve.sharded import ShardedExtractionService
@@ -396,3 +399,203 @@ class TestQuantizeStraddle:
         cold = Slicer(small_cube()).extract_plan(
             self.box_req(1.0 + self.JITTER))[0]
         np.testing.assert_array_equal(plan.offsets, cold.offsets)
+
+
+# ---------------------------------------------------------------------------
+# The key memo: a repeated raw request's key without its decomposition
+# ---------------------------------------------------------------------------
+
+_CONCAVE = np.array([[1.0, 1.0], [9.0, 1.0], [9.0, 9.0], [5.0, 4.0],
+                     [1.0, 9.0]])
+
+# Two vertex lists whose reprs are equal: NumPy's and JAX's abbreviate.
+_LONG = np.stack([np.linspace(0.0, 8.0, 1500), np.zeros(1500)], -1)
+_LONG_MOVED = _LONG.copy()
+_LONG_MOVED[750, 1] = 1.0
+
+# Each kind builds fresh shapes, arrays included, on every call.
+SHAPE_KINDS = {
+    "select": lambda: [Select("c", [1.0, 3.0])],
+    "span": lambda: [Span("a", 2.0, 7.5), Select("c", [2.0])],
+    "point": lambda: [Point(("a", "b"), [3.0, 4.0])],
+    "box": lambda: [Box(("a", "b"), [1.0, 1.0], [5.0, 6.0])],
+    "convex": lambda: [ConvexPolytope(("a", "b"), _TRI.copy())],
+    "disk": lambda: [Disk(("a", "b"), (6.0, 6.0), 2.5)],
+    "ellipsoid": lambda: [Ellipsoid(("a", "b", "c"), (5.0, 5.0, 5.0),
+                                    (3.0, 2.0, 2.0))],
+    "polygon": lambda: [Polygon(("a", "b"), _CONCAVE.copy()),
+                        Select("c", [4.0])],
+    "union": lambda: [Union([Box(("a", "b"), [0.0, 0.0], [3.0, 3.0]),
+                             Disk(("a", "b"), (6.0, 6.0), 2.0)])],
+    "path": lambda: [Path(("a", "b"),
+                          Disk(("a", "b"), (0.0, 0.0), 1.0, segments=8),
+                          np.array([[2.0, 2.0], [6.0, 3.0], [8.0, 8.0]]))],
+}
+
+
+def _spy_polytopes(monkeypatch):
+    """Record every ``Request.polytopes`` call (the decomposition)."""
+    calls = []
+    real = Request.polytopes
+
+    def spy(self):
+        calls.append(self)
+        return real(self)
+
+    monkeypatch.setattr(Request, "polytopes", spy)
+    return calls
+
+
+class TestKeyMemo:
+    @pytest.mark.parametrize("kind", SHAPE_KINDS)
+    def test_memo_key_is_the_canonical_hash(self, kind):
+        make = SHAPE_KINDS[kind]
+        svc = ShardedExtractionService(small_cube(), shards=1)
+        first = svc.submit_batch([Request(make())])[0]
+        second = svc.submit_batch([Request(make())])[0]
+        s = svc.stats
+        assert (s.key_memo_misses, s.key_memo_hits) == (1, 1)
+        assert second.key == first.key == Request(make()).canonical_hash(
+            svc.tol, svc.periods)
+
+    @pytest.mark.parametrize("kind", SHAPE_KINDS)
+    def test_fresh_requests_share_one_digest(self, kind):
+        make = SHAPE_KINDS[kind]
+        periods = {"b": 12.0}
+        assert Request(make()).spec_digest() == Request(make()).spec_digest()
+        assert (Request(make()).spec_digest(CANON_TOL, periods)
+                == Request(make()).spec_digest(CANON_TOL, dict(periods)))
+
+    @pytest.mark.parametrize("a, b, args_b", [
+        pytest.param([ConvexPolytope(("a", "b"), _TRI)],
+                     [ConvexPolytope(("a", "b"),
+                                     _TRI + [[0.0, 0.0], [10 * CANON_TOL, 0.0],
+                                             [0.0, 0.0]])],
+                     (CANON_TOL, None), id="vertex-moved-past-tol"),
+        pytest.param([Polygon(("a", "b"), _CONCAVE)],
+                     [Polygon(("a", "b"), _CONCAVE)],
+                     (CANON_TOL, {"b": 12.0}), id="periods"),
+        pytest.param([Select("c", [1.0])], [Select("c", [1])],
+                     (CANON_TOL, None), id="int-against-float"),
+        pytest.param([Box(("a", "b"), [1.0, 1.0], [5.0, 6.0])],
+                     [Box(("a", "b"), [1.0, 1.0], [5.0, 6.0])],
+                     (1e-6, None), id="tol"),
+        pytest.param([ConvexPolytope(("a", "b"), _TRI)],
+                     [ConvexPolytope(("a", "b"), _TRI.astype(np.float32))],
+                     (CANON_TOL, None), id="dtype"),
+        pytest.param([ConvexPolytope(("a", "b"), _LONG)],
+                     [ConvexPolytope(("a", "b"), _LONG_MOVED)],
+                     (CANON_TOL, None), id="long-array-same-repr"),
+        pytest.param([ConvexPolytope(("a", "b"), jnp.asarray(_LONG))],
+                     [ConvexPolytope(("a", "b"), jnp.asarray(_LONG_MOVED))],
+                     (CANON_TOL, None), id="long-jax-array-same-repr"),
+        pytest.param([Union([Box(("a", "b"), [0, 0], [3, 3]),
+                             Disk(("a", "b"), (6.0, 6.0), 2.0)])],
+                     [Union([Disk(("a", "b"), (6.0, 6.0), 2.0),
+                             Box(("a", "b"), [0, 0], [3, 3])])],
+                     (CANON_TOL, None), id="member-order"),
+    ])
+    def test_different_raw_requests_differ(self, a, b, args_b):
+        assert (Request(a).spec_digest(CANON_TOL, None)
+                != Request(b).spec_digest(*args_b))
+
+    @pytest.mark.parametrize("wrap", [np.asarray, jnp.asarray])
+    def test_the_long_arrays_share_a_repr(self, wrap):
+        assert repr(wrap(_LONG)) == repr(wrap(_LONG_MOVED))
+
+    @pytest.mark.parametrize("kind", SHAPE_KINDS)
+    def test_memo_hit_skips_the_decomposition(self, kind, monkeypatch):
+        make = SHAPE_KINDS[kind]
+        cube = small_cube()
+        data = np.arange(cube.n_elements, dtype=np.float64)
+        svc = ShardedExtractionService(cube, shards=1)
+        first = svc.submit_batch([Request(make())], data)[0]
+        calls = _spy_polytopes(monkeypatch)
+        again = Request(make())
+        second = svc.submit_batch([again], data)[0]
+        assert calls == []
+        assert second.cached and second.plan is first.plan
+        np.testing.assert_array_equal(second.values, first.values)
+
+    def test_memo_hit_whose_plan_was_evicted_plans_again(self):
+        svc = ShardedExtractionService(small_cube(), shards=2,
+                                       capacity_per_shard=1)
+        # two geometries whose keys share a shard evict each other's
+        # plan, while the memo, two keys long, keeps both digests
+        shard_of = {}
+        for shift in np.arange(0.0, 4.0, 0.5):
+            key = tri_request(shift).canonical_hash(svc.tol, svc.periods)
+            shard_of.setdefault(svc.shards.entry_of(key)[0], []).append(shift)
+        a, b = next(v for v in shard_of.values() if len(v) >= 2)[:2]
+        first = svc.submit_batch([tri_request(a)])[0]
+        svc.submit_batch([tri_request(b)])
+        again = svc.submit_batch([tri_request(a)])[0]
+        s = svc.stats
+        assert s.evictions == 2 and s.key_memo_hits == 1
+        assert not again.cached and again.key == first.key
+        np.testing.assert_array_equal(again.plan.offsets, first.plan.offsets)
+
+    @pytest.mark.parametrize("shards", [1, 2])
+    def test_eviction_holds_the_memo_at_capacity(self, shards):
+        svc = ShardedExtractionService(small_cube(), shards=shards,
+                                       capacity_per_shard=2)
+        capacity = 2 * shards
+        for shift in range(capacity + 2):
+            svc.submit_batch([tri_request(float(shift))])
+            assert len(svc._keys) == min(shift + 1, capacity)
+        # the least recently used digests went first
+        svc.submit_batch([tri_request(0.0)])
+        assert svc.stats.key_memo_hits == 0
+        svc.submit_batch([tri_request(float(capacity + 1))])
+        assert svc.stats.key_memo_hits == 1
+
+    def test_memo_counts_add_up_to_the_lookups(self):
+        cube = small_cube()
+        data = np.arange(cube.n_elements, dtype=np.float64)
+        svc = ShardedExtractionService(cube, shards=2, capacity_per_shard=2)
+        batches = [[tri_request(0.0), tri_request(0.0), tri_request(1.0)],
+                   [tri_request(2.0), tri_request(0.0)],
+                   [tri_request(3.0), tri_request(1.0), tri_request(1.0)]]
+        for batch in batches:
+            svc.submit_batch(batch, data)
+        s = svc.stats
+        assert (s.key_memo_hits + s.key_memo_misses
+                == s.lookups + s.batch_dedup == 8)
+
+    @pytest.mark.parametrize("make", [
+        pytest.param(lambda: _Corner(), id="plain-subclass"),
+        pytest.param(lambda: _PaddedBox(("a", "b"), [2.0, 2.0], [4.0, 4.0],
+                                        pad=1.0), id="undecorated-subclass"),
+    ])
+    def test_shape_that_is_not_a_dataclass_is_hashed_each_time(self, make):
+        svc = ShardedExtractionService(small_cube(), shards=1)
+        assert Request([make()]).spec_digest() is None
+        first = svc.submit_batch([Request([make()])])[0]
+        second = svc.submit_batch([Request([make()])])[0]
+        s = svc.stats
+        assert (s.key_memo_misses, s.key_memo_hits) == (2, 0)
+        assert second.cached and second.key == first.key
+
+    def test_undecorated_subclass_attributes_split_keys(self):
+        """A field-only digest would give both boxes one key."""
+        svc = ShardedExtractionService(small_cube(), shards=1)
+        small, large = (svc.submit_batch([Request([_PaddedBox(
+            ("a", "b"), [4.0, 4.0], [5.0, 5.0], pad=p)])])[0]
+            for p in (0.0, 2.0))
+        assert small.key != large.key
+        assert small.plan.n_points < large.plan.n_points
+
+
+class _Corner(Shape):
+    def polytopes(self):
+        return ConvexPolytope(("a", "b"), _TRI).polytopes()
+
+
+class _PaddedBox(Box):
+    def __init__(self, axes, lows, highs, pad):
+        super().__init__(axes, lows, highs)
+        self.pad = pad
+
+    def polytopes(self):
+        return Box(self.axes, [v - self.pad for v in self.lows],
+                   [v + self.pad for v in self.highs]).polytopes()

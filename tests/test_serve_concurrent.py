@@ -19,12 +19,13 @@ swarm while the scheduled lane runs the full one:
 
 import hashlib
 import os
+import sys
 import threading
 
 import numpy as np
 import pytest
 
-from repro.core import PolytopeExtractor, gather
+from repro.core import PolytopeExtractor, Request, gather
 from repro.dataplane.weather import (IrregularWeatherCube, WeatherCube,
                                      request_population)
 from repro.serve.extraction import PlanCache
@@ -129,6 +130,40 @@ class TestSubmitBatchSwarm:
         assert len(svc.shards) == distinct
         # 2 in-batch duplicates per batch, every batch
         assert s.batch_dedup == 2 * N_THREADS * N_ITERS
+        # every request's key came from the memo or from its hash
+        assert (s.key_memo_hits + s.key_memo_misses
+                == s.lookups + s.batch_dedup == 8 * N_THREADS * N_ITERS)
+
+    def test_fresh_request_objects_find_memoized_keys(self, weather):
+        """Each submission a new ``Request`` over the same shapes, as
+        arrivals are: keys come from the memo, not from a decomposition
+        of each object, and the counts still add up under contention."""
+        wc, data, population = weather
+        svc = ShardedExtractionService(wc.cube, shards=4)
+        hot = population[:6]
+        refs = reference_values(wc.cube, data, hot)
+        keys = [r.canonical_hash(svc.tol, svc.periods) for r in hot]
+
+        def worker(tid):
+            for j in range(N_ITERS):
+                picks = [(tid + j + k) % len(hot) for k in range(4)]
+                batch = [Request(list(hot[i].shapes)) for i in picks]
+                for i, res in zip(picks, svc.submit_batch(batch, data)):
+                    assert res.key == keys[i]
+                    assert np.array_equal(res.values, refs[i])
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            run_swarm(N_THREADS, worker)
+        finally:
+            sys.setswitchinterval(interval)
+        s = svc.stats
+        total = 4 * N_THREADS * N_ITERS
+        assert (s.key_memo_hits + s.key_memo_misses
+                == s.lookups + s.batch_dedup == total)
+        assert s.key_memo_misses >= len(hot)
+        assert s.key_memo_hits > 0 and len(svc._keys) == len(hot)
 
     @pytest.mark.parametrize("shards", [1, 4])
     def test_seam_shifted_requests_share_one_plan(self, irregular, shards):
@@ -155,6 +190,7 @@ class TestSubmitBatchSwarm:
         assert s.misses == 1           # one plan for both seam phrasings
         assert s.hits == N_THREADS * N_ITERS - 1
         assert s.lookups == s.hits + s.misses
+        assert s.key_memo_hits + s.key_memo_misses == N_THREADS * N_ITERS
 
 
 # ---------------------------------------------------------------------------
@@ -182,6 +218,9 @@ class TestAdmissionQueue:
         total = N_THREADS * N_ITERS
         assert adm.submitted == total
         assert adm.served == total
+        s = svc.stats
+        assert (s.key_memo_hits + s.key_memo_misses
+                == s.lookups + s.batch_dedup == total)
         assert 0 <= adm.coalesced <= adm.submitted
         assert adm.windows >= 1
         assert adm.coalescing_factor >= 1.0
